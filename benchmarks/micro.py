@@ -84,13 +84,13 @@ def bench_micro() -> List[Tuple[str, float, str]]:
     b, h, hkv, d, s = 4, 16, 4, 128, 2048
     q = jnp.asarray(rng.standard_normal((b, h, d)).astype(np.float32))
     kc = jnp.asarray(
-        rng.standard_normal((b, s, hkv, d)).astype(np.float32) * 0.3)
+        rng.standard_normal((b, s, hkv * d)).astype(np.float32) * 0.3)
     vc = jnp.asarray(
-        rng.standard_normal((b, s, hkv, d)).astype(np.float32) * 0.3)
+        rng.standard_normal((b, s, hkv * d)).astype(np.float32) * 0.3)
     lens = jnp.full((b,), s, jnp.int32)
     kp, vp = R.pack_ref(kc, 16), R.pack_ref(vc, 16)
     f_packed = jax.jit(
-        lambda q_, k_, v_, l_: R.kv_decode_ref(q_, k_, v_, 16, d, l_))
+        lambda q_, k_, v_, l_: R.kv_decode_ref(q_, k_, v_, 16, hkv, l_))
     us_pk = _time(f_packed, q, kp, vp, lens) * 1e6
     rows.append(("micro.kv_decode_packed16", us_pk,
                  f"kv_bytes={kp.size * 4 * 2}"))

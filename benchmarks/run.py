@@ -54,6 +54,9 @@ def main() -> None:
                     help="comma-separated bench name filter")
     args = ap.parse_args()
 
+    from repro import compat
+    compat.enable_compile_cache()
+
     from benchmarks.calibration import bench_calibration
     from benchmarks.micro import bench_micro
     from benchmarks.packed_path import bench_packed_path

@@ -130,22 +130,18 @@ def _kernel_parity_probe() -> dict:
     bits, d, page, hkv, h, b, mp = 8, 32, 4, 2, 4, 3, 3
     n_pages = 1 + b * mp
     rng = np.random.default_rng(5)
-    w = d * bits // 32
-    k_pool = kref.pack_ref(jnp.asarray(
-        rng.standard_normal((n_pages, page, hkv, d)), jnp.float32), bits
-    ).reshape(n_pages, page, hkv, w)
-    v_pool = kref.pack_ref(jnp.asarray(
-        rng.standard_normal((n_pages, page, hkv, d)), jnp.float32), bits
-    ).reshape(n_pages, page, hkv, w)
+    k_pool, v_pool = (kref.pack_ref(jnp.asarray(
+        rng.standard_normal((n_pages, page, hkv * d)), jnp.float32), bits)
+        for _ in range(2))
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
     table = jnp.asarray(
         rng.permutation(np.arange(1, n_pages))[: b * mp].reshape(b, mp),
         jnp.int32)
     kv_len = jnp.asarray([1, page, b * page - 1], jnp.int32)
-    got = paged_attention(q, k_pool, v_pool, table, kv_len, bits, d,
+    got = paged_attention(q, k_pool, v_pool, table, kv_len, bits, hkv,
                           interpret=True)
     want = kref.paged_attention_ref(q, k_pool, v_pool, table, kv_len,
-                                    bits, d)
+                                    bits, hkv)
     err = float(jnp.max(jnp.abs(got - want)))
     if err > 2e-5:
         raise AssertionError(

@@ -6,9 +6,9 @@
 #
 # Fails on any collection error (the explicit --collect-only pass turns
 # a silently-skipped broken module into a hard failure) and on any
-# mesh-matrix cell, so a regression in either compat API path
-# (0.4.x thread_resources / >=0.5 abstract mesh) is caught without
-# hardware.
+# mesh-matrix cell, so a regression in the compat mesh path is caught
+# without hardware. This is the CPU suite; `python chip_smoke.py` is the
+# check on a TPU.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

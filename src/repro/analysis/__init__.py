@@ -13,7 +13,7 @@ Four passes over the real entry points (``LM.decode_step`` /
 3. **plan-soundness verifier** (``soundness``): plan int widths vs.
    range-analysis proofs (silent-clipping detection), float widths vs.
    the Table 3 ladder and overflow thresholds;
-4. **sharding/donation lints** (``sharding_lint``): the group-of-32
+4. **sharding/donation lints** (``sharding_lint``): the whole-tile
    packed-axis rule and donated-buffer read-after-overwrite.
 
 CLI: ``python -m repro.analysis.lint --arch X [--plan plan.json]

@@ -2,15 +2,16 @@
 
 Two whole-program invariants that today only hold by convention:
 
-* **group-of-32 packed axis** — a packed payload may shard its last
-  (word) axis only when the *logical* axis length is a multiple of
-  ``32 x shard count``; anything else hands two devices halves of one
-  group's shift/or network (``distributed.sharding.spec_for_packed``
-  docstring has the full argument). The lint re-derives the expected
-  rule per planned leaf at several tensor-parallel degrees and reports
-  any spec that keeps a misaligned shard (error) — plus perf notes
-  (info) where a hot leaf's packed axis must replicate because the
-  logical width is group-misaligned.
+* **whole-tile packed axis** — a packed payload may shard its last
+  (word) axis only when every shard is a whole number of lane tiles at
+  the axis' own lane width (``core.bitpack.shard_aligned``); anything
+  else hands two devices halves of one tile's shift/or network
+  (``distributed.sharding.spec_for_packed`` docstring has the full
+  argument). The lint re-derives the expected rule per planned leaf at
+  several tensor-parallel degrees and reports any spec that keeps a
+  misaligned shard (error) — plus perf notes (info) where a hot leaf's
+  packed axis must replicate because the logical width is
+  tile-misaligned.
 * **donated-buffer read-after-overwrite** — ``decode_step`` donates the
   decode state (serving jits with ``donate_argnums``); a donated invar
   that is overwritten (fed to an in-place-shaped op: a
@@ -40,7 +41,7 @@ _TP_DEGREES = (2, 4, 8)
 
 def lint_sharding(cfg, plan=None, params: Optional[Dict] = None,
                   ) -> List[Finding]:
-    """Check the group-of-32 rule for every planned leaf at each TP
+    """Check the whole-tile rule for every planned leaf at each TP
     degree, using the ``axis_sizes`` override (no mesh needed)."""
     findings: List[Finding] = []
     if params is None:
@@ -50,36 +51,37 @@ def lint_sharding(cfg, plan=None, params: Optional[Dict] = None,
         plan = uniform_plan(params, cfg.resolved_weight_bits)
     packed = repack(params, plan)
 
-    leaves: List[Tuple[str, Tuple[int, ...]]] = []
+    leaves: List[Tuple[str, Tuple[int, ...], int]] = []
 
     def visit(path, leaf):
         if is_packed(leaf):
-            leaves.append((path_str(path), tuple(leaf.logical_shape)))
+            leaves.append((path_str(path), tuple(leaf.logical_shape),
+                           leaf.bits))
 
     jax.tree_util.tree_map_with_path(visit, packed, is_leaf=is_packed)
 
     n_checked = 0
-    for path, logical in sorted(leaves):
+    for path, logical, bits in sorted(leaves):
         base = tuple(spec_for(path, logical))
         base_last = base[-1] if len(base) == len(logical) and base else None
         dropped_at: List[int] = []
         for tp in _TP_DEGREES:
             sizes = {"model": tp, "data": 1}
-            spec = tuple(spec_for_packed(path, logical,
+            spec = tuple(spec_for_packed(path, logical, bits,
                                          axis_sizes=sizes))
             n_checked += 1
             last = spec[-1] if spec else None
             if last is not None:
                 shards = _spec_shards(last, sizes)
-                if shards > 1 and logical[-1] % (bitpack.GROUP * shards):
+                if not bitpack.shard_aligned(logical[-1], bits, shards):
                     findings.append(Finding(
                         check="sharding", severity="error", path=path,
                         message=(
-                            f"group-of-32 violation: packed axis of "
-                            f"{path} (logical last dim {logical[-1]}) "
-                            f"sharded {shards}-way over {last!r} but "
-                            f"{logical[-1]} % {bitpack.GROUP * shards} "
-                            f"!= 0 — a bit-group would straddle devices"),
+                            f"whole-tile violation: packed axis of "
+                            f"{path} (logical last dim {logical[-1]}, "
+                            f"AF{bits}) sharded {shards}-way over "
+                            f"{last!r} — a lane tile would straddle "
+                            f"devices"),
                         detail={"logical_shape": list(logical),
                                 "tp": tp, "entry": str(last)},
                     ))
@@ -92,8 +94,8 @@ def lint_sharding(cfg, plan=None, params: Optional[Dict] = None,
                 message=(
                     f"perf: packed axis of {path} (logical last dim "
                     f"{logical[-1]}) replicates at every TP degree "
-                    f"{_TP_DEGREES} — the logical width is not a "
-                    f"multiple of 32 x shards, so the packed leaf "
+                    f"{_TP_DEGREES} — the logical width does not split "
+                    f"into whole lane tiles, so the packed leaf "
                     "cannot tensor-parallelize its hot axis"),
                 detail={"logical_shape": list(logical),
                         "degrees": dropped_at},
@@ -102,7 +104,7 @@ def lint_sharding(cfg, plan=None, params: Optional[Dict] = None,
         findings.append(Finding(
             check="sharding", severity="info",
             message=(
-                f"group-of-32 rule holds for {len(leaves)} packed "
+                f"whole-tile rule holds for {len(leaves)} packed "
                 f"leaves x {len(_TP_DEGREES)} TP degrees "
                 f"({n_checked} specs checked)"),
         ))

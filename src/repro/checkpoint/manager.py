@@ -20,7 +20,10 @@ Design points for 1000+ node operation:
   * **Packed state passes through untouched** — PackedTensor payloads are
     uint32 leaves + static aux recorded in the manifest, so checkpoints of
     compressed state are bits/32 the size of f32 checkpoints, exactly the
-    paper's footprint claim applied to persistence.
+    paper's footprint claim applied to persistence. Each packed entry
+    names its word layout (``bitpack.LAYOUT``); restore refuses a payload
+    packed in another layout instead of decoding it with the wrong
+    shifts.
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import numpy as np
 
 from repro import compat
 
+from repro.core import bitpack
 from repro.core.tensor_store import PackedTensor, is_packed
 
 
@@ -120,6 +124,12 @@ class CheckpointManager:
         leaves = []
         for entry in manifest["leaves"]:
             arr = arrays[entry["key"]]
+            if entry.get("packed") and entry.get("layout") != bitpack.LAYOUT:
+                raise ValueError(
+                    f"checkpoint {d}: packed leaf {entry['key']!r} uses word "
+                    f"layout {entry.get('layout', 'group-of-32')!r}, this "
+                    f"build reads {bitpack.LAYOUT!r}; re-pack it from the "
+                    "dense values")
             if entry.get("packed"):
                 leaves.append(PackedTensor(
                     data=arr,
@@ -156,7 +166,8 @@ class CheckpointManager:
             if is_packed(leaf):
                 payload[key] = np.asarray(leaf.data)
                 leaves_meta.append({
-                    "key": key, "packed": True, "bits": leaf.bits,
+                    "key": key, "packed": True, "layout": bitpack.LAYOUT,
+                    "bits": leaf.bits,
                     "kind": leaf.kind, "signed": leaf.signed,
                     "logical_shape": list(leaf.logical_shape),
                     "out_dtype": np.dtype(leaf.out_dtype).name,

@@ -1,18 +1,14 @@
-"""Version-portable JAX substrate — the co-design seam.
+"""The JAX substrate seam.
 
 Everything above the packing kernels talks to JAX through this package
-instead of scattered ``jax.*`` attribute lookups, so the distributed /
-model / serving stack runs unchanged across JAX generations:
-
-  * jax 0.4.x — ``jax.experimental.shard_map.shard_map(check_rep=...)``,
-    mesh context queried from the legacy ``thread_resources``
-    thread-local that ``with mesh:`` populates.
-  * jax >= 0.5 — ``jax.shard_map(check_vma=...)`` and
-    ``jax.sharding.get_abstract_mesh()`` / ``use_mesh``.
-
-Feature detection happens once at import time; ``support_matrix()``
-reports which path each seam resolved to (tests and the dry-run print
-it so CI logs always show the active generation).
+instead of scattered ``jax.*`` attribute lookups: mesh construction and
+queries (``meshes``), ``shard_map`` (``shardmap``), PRNG and donation
+helpers (``prng``), pytree utilities (``trees``), the Pallas execution
+mode (``pallas``) and the persistent compilation cache (``cache``). The
+supported generation is JAX 0.9; when an API moves, the change lands
+here and not in every consumer. ``support_matrix()`` reports what the
+run-time choices resolved to (the dry-run prints it, benchmarks embed
+it).
 
 The paper's framing applies directly: the register-file work survives
 hardware generations because the compression seam lives in one
@@ -29,10 +25,8 @@ from repro.compat.pallas import (
     on_tpu,
     pallas_interpret_default,
 )
+from repro.compat.cache import enable_compile_cache
 from repro.compat.meshes import (
-    ABSTRACT_MESH_PATH,
-    NATIVE_MAKE_MESH,
-    USE_MESH_PATH,
     current_mesh,
     current_mesh_axis_names,
     current_mesh_axis_sizes,
@@ -41,12 +35,7 @@ from repro.compat.meshes import (
     with_sharding_constraint,
 )
 from repro.compat.prng import jit, prng_fold_in, prng_key, prng_split
-from repro.compat.shardmap import (
-    NATIVE_SHARD_MAP,
-    SHARD_MAP_CHECK_KW,
-    axis_size,
-    shard_map,
-)
+from repro.compat.shardmap import axis_size, shard_map
 from repro.compat.trees import (
     path_str,
     tree_flatten,
@@ -83,23 +72,15 @@ __all__ = [
     "on_tpu",
     "default_kernel_mode",
     "pallas_interpret_default",
+    "enable_compile_cache",
 ]
 
 
 def support_matrix() -> dict:
-    """Which implementation each seam resolved to on this jax."""
+    """The JAX version and what the run-time choices resolved to here:
+    the Pallas execution mode and the kernel backend."""
     return {
         "jax": jax.__version__,
-        "shard_map": ("jax.shard_map" if NATIVE_SHARD_MAP
-                      else "jax.experimental.shard_map"),
-        "shard_map_check_kw": SHARD_MAP_CHECK_KW,
-        "axis_size": ("jax.lax.axis_size"
-                      if hasattr(jax.lax, "axis_size") else "psum(1, axis)"),
-        "mesh_query": ("abstract_mesh" if ABSTRACT_MESH_PATH
-                       else "thread_resources"),
-        "mesh_context": "use_mesh" if USE_MESH_PATH else "with_mesh",
-        "make_mesh": ("jax.make_mesh" if NATIVE_MAKE_MESH
-                      else "mesh_utils.create_device_mesh"),
         "pallas": "interpret" if pallas_interpret_default() else "compiled",
         "kernel_mode": default_kernel_mode(),
     }

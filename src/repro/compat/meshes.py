@@ -1,11 +1,12 @@
-"""Mesh construction and mesh-context queries across jax generations.
+"""Mesh construction and mesh-context queries.
 
-jax >= 0.5 exposes the active mesh as an ``AbstractMesh`` via
-``jax.sharding.get_abstract_mesh()`` (set by ``use_mesh`` and, for
-compatibility, by ``with mesh:``).  jax 0.4.x keeps it in a private
-thread-local (``thread_resources``) that only ``with mesh:`` populates.
-Both generations funnel through ``current_mesh()`` here; this module is
-the single sanctioned place that pokes ``jax._src.mesh``.
+Meshes are built with ``Auto`` axis types: the model stack annotates
+activations with ``with_sharding_constraint`` against a bare
+``PartitionSpec`` and lets GSPMD propagate the rest, which the
+``Explicit`` default of ``jax.make_mesh`` refuses. The active mesh is
+the ``AbstractMesh`` that ``jax.set_mesh`` installs — visible at trace
+time (inside jit) and eagerly. This module is the single place that
+decides both.
 """
 from __future__ import annotations
 
@@ -13,38 +14,14 @@ import contextlib
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-_get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-_use_mesh = getattr(jax.sharding, "use_mesh", None)
-_make_mesh = getattr(jax, "make_mesh", None)
-
-ABSTRACT_MESH_PATH = _get_abstract_mesh is not None
-USE_MESH_PATH = _use_mesh is not None
-NATIVE_MAKE_MESH = _make_mesh is not None
-
-
-def _legacy_physical_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:          # future jax: private module gone
-        return None
-    phys = thread_resources.env.physical_mesh
-    return None if phys.empty else phys
+from jax.sharding import AxisType, Mesh
 
 
 def current_mesh():
-    """The mesh made current via ``with mesh:`` / ``use_mesh``, else None.
-
-    Returns an ``AbstractMesh`` on the >=0.5 path and a physical ``Mesh``
-    on the legacy path; both expose ``axis_names``.  Valid at trace time
-    (inside jit) and eagerly.
-    """
-    if ABSTRACT_MESH_PATH:
-        m = _get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    return _legacy_physical_mesh()
+    """The ``AbstractMesh`` made current via :func:`mesh_context`, else
+    None. Valid at trace time (inside jit) and eagerly."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def current_mesh_axis_names() -> Tuple[str, ...]:
@@ -56,46 +33,34 @@ def current_mesh_axis_sizes() -> Dict[str, int]:
     m = current_mesh()
     if m is None:
         return {}
-    sizes = getattr(m, "axis_sizes", None)     # AbstractMesh
-    if sizes is None:
-        sizes = tuple(m.devices.shape)         # physical Mesh
-    return dict(zip(m.axis_names, (int(s) for s in sizes)))
+    return dict(zip(m.axis_names, (int(s) for s in m.axis_sizes)))
 
 
 def make_mesh(axis_shapes: Sequence[int],
               axis_names: Sequence[str],
               devices=None) -> Mesh:
-    """``jax.make_mesh`` where available, device-mesh assembly otherwise."""
-    if NATIVE_MAKE_MESH:
-        if devices is None:
-            return _make_mesh(tuple(axis_shapes), tuple(axis_names))
-        return _make_mesh(tuple(axis_shapes), tuple(axis_names),
-                          devices=devices)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                         devices=devices)
-    return Mesh(devs, tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         **kwargs)
 
 
 @contextlib.contextmanager
 def mesh_context(mesh: Optional[Mesh]):
-    """Make ``mesh`` current for sharding queries on whichever mechanism
-    this jax provides (``use_mesh`` when present, legacy ``with mesh:``).
+    """Make ``mesh`` current for sharding queries (``jax.set_mesh``).
     ``None`` is a no-op so callers can thread an optional mesh."""
     if mesh is None:
         yield None
-    elif USE_MESH_PATH:
-        with _use_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+        return
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def with_sharding_constraint(x, spec):
     """Annotate ``x`` with a sharding; resolved against the current mesh.
 
-    Stable across supported generations — routed through the seam so a
-    future rename lands in exactly one file.
+    Routed through the seam so a future rename lands in exactly one
+    file.
     """
     return jax.lax.with_sharding_constraint(x, spec)

@@ -11,6 +11,10 @@ the execution substrate:
     without a Mosaic path) -> ``interpret=True`` (Python-interpreter
     validation of the identical kernel body).
 
+On a TPU nothing resolves to interpret mode or to the oracle unless the
+caller asks for it (``interpret=True``, ``set_backend("jnp")``, an op's
+``fallback=True``).
+
 Unlike the other compat seams (pure ``hasattr`` checks), backend
 detection initializes the JAX runtime, so it is deferred to the *first
 kernel call* and cached — merely importing ``repro.compat`` must stay
@@ -34,11 +38,10 @@ import jax
 @functools.lru_cache(maxsize=None)
 def on_tpu() -> bool:
     """Whether a real TPU backend is present (cached; first call
-    initializes the JAX backend, so only kernel/launch code should ask)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend init failure
-        return False
+    initializes the JAX backend, so only kernel/launch code should ask).
+    A backend that fails to initialize raises: reporting "not TPU" would
+    quietly route a TPU run onto the jnp oracle."""
+    return jax.default_backend() == "tpu"
 
 
 def pallas_interpret_default(interpret: Optional[bool] = None) -> bool:

@@ -1,27 +1,13 @@
-"""``shard_map`` across jax generations.
+"""``shard_map`` through the seam.
 
-jax 0.4.x ships it as ``jax.experimental.shard_map.shard_map`` with a
-``check_rep`` kwarg; jax >= 0.5 promotes it to ``jax.shard_map`` and
-later renames the replication check to ``check_vma``.  Callers use the
-version-neutral ``check_replication`` and the seam maps it onto
-whatever kwarg the installed implementation takes.
+Callers use the neutral ``check_replication`` flag; the seam maps it onto
+``jax.shard_map``'s ``check_vma`` so a future rename lands here.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Callable
 
 import jax
-
-_impl = getattr(jax, "shard_map", None)
-NATIVE_SHARD_MAP = _impl is not None
-if _impl is None:
-    from jax.experimental.shard_map import shard_map as _impl
-
-_sig_params = inspect.signature(_impl).parameters
-SHARD_MAP_CHECK_KW = ("check_vma" if "check_vma" in _sig_params
-                      else "check_rep" if "check_rep" in _sig_params
-                      else None)
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
@@ -29,27 +15,14 @@ def shard_map(f: Callable, *, mesh, in_specs, out_specs,
     """Map ``f`` over mesh shards with manual collectives.
 
     ``check_replication=False`` disables the out-spec replication check
-    (``check_rep`` on 0.4.x, ``check_vma`` on newer jax) — needed for
-    programs whose replication the checker cannot prove, e.g. the masked
-    psum that ends the pipeline schedule.
+    (``check_vma``) — needed for programs whose replication the checker
+    cannot prove, e.g. the masked psum that ends the pipeline schedule.
     """
-    kwargs = {}
-    if SHARD_MAP_CHECK_KW is not None:
-        kwargs[SHARD_MAP_CHECK_KW] = check_replication
-    return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 **kwargs)
-
-
-_native_axis_size = getattr(jax.lax, "axis_size", None)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a named (manual) mesh axis, inside shard_map.
-
-    ``jax.lax.axis_size`` only exists on jax >= 0.5; the 0.4.x idiom is
-    a constant-folded ``psum(1, axis)``, which returns a Python int for
-    statically sized axes — both usable in Python control flow.
-    """
-    if _native_axis_size is not None:
-        return _native_axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a named (manual) mesh axis, inside shard_map —
+    usable in Python control flow."""
+    return jax.lax.axis_size(axis_name)

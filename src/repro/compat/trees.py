@@ -1,29 +1,19 @@
 """Pytree utilities through the seam.
 
-``jax.tree_util`` has been stable for years but ``jax.tree.*`` is the
-blessed namespace going forward (and the one whose semantics track new
-releases); resolve once here and let the stack import from one place.
-Path-keyed variants only exist under ``jax.tree_util`` on 0.4.x, so
-those are feature-detected too.
+The stack imports its tree functions from here (the ``jax.tree``
+namespace), so a future rename lands in one file.
 """
 from __future__ import annotations
 
 import jax
 
-_tree_ns = getattr(jax, "tree", None)
-
-tree_map = getattr(_tree_ns, "map", None) or jax.tree_util.tree_map
-tree_leaves = getattr(_tree_ns, "leaves", None) or jax.tree_util.tree_leaves
-tree_flatten = (getattr(_tree_ns, "flatten", None)
-                or jax.tree_util.tree_flatten)
-tree_unflatten = (getattr(_tree_ns, "unflatten", None)
-                  or jax.tree_util.tree_unflatten)
-tree_structure = (getattr(_tree_ns, "structure", None)
-                  or jax.tree_util.tree_structure)
-tree_map_with_path = (getattr(_tree_ns, "map_with_path", None)
-                      or jax.tree_util.tree_map_with_path)
-tree_flatten_with_path = (getattr(_tree_ns, "flatten_with_path", None)
-                          or jax.tree_util.tree_flatten_with_path)
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
+tree_map_with_path = jax.tree.map_with_path
+tree_flatten_with_path = jax.tree.flatten_with_path
 
 
 def path_str(path) -> str:

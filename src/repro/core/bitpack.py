@@ -14,6 +14,9 @@ same arithmetic with VMEM tiling.
 """
 from __future__ import annotations
 
+import math
+from typing import Tuple
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -106,70 +109,156 @@ def stream_bits(n: int, width: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Group-of-32 layout: the TPU-shardable packing used by the tensor store
+# Lane-tiled layout: the TPU-tileable packing used by the tensor store
 # ---------------------------------------------------------------------------
-# 32 consecutive codes of ``width`` bits occupy exactly ``width`` 32-bit
-# words, so a tensor packed along its last axis keeps *static* word/offset
-# arithmetic (every shift below is a Python constant), stays elementwise
-# (no dynamic gathers -> XLA fuses it, Pallas tiles it), and shards evenly
-# whenever the packed axis length is a multiple of 32 x (shard count).
-# This is the slice/indirection scheme of Section 3.2 re-blocked for a
-# vector unit: the "indirection" collapses to static mux selects exactly
-# like the TVE's mask-driven 9:1 muxes.
+# A ``width``-bit code shares 32-bit words with its neighbours in groups of
+# ``g = 32 / gcd(width, 32)`` codes, which fill exactly ``wg = g*width/32``
+# words (AF8: 4 codes -> 1 word, AF12: 8 -> 3, AF16: 2 -> 1). Along the
+# packed (last) axis, codes are cut into chunks of ``L`` lanes (``L`` = 128
+# for every axis of at least ``g`` x 128 codes, the full axis / g below
+# that); ``g`` consecutive code chunks form a *tile* that packs into ``wg``
+# consecutive word chunks. Lane ``l`` of a tile's word chunks holds lane
+# ``l`` of its code chunks as a dense little-endian stream — the paper's
+# slice-packed register, one per lane.
+#
+# The point of the layout is what unpacking it needs: every code chunk is
+# a whole-vreg shift/or/mask of one or two word chunks at the *same* lanes,
+# so a Mosaic kernel unpacks an (8k, 128) tile with no reshape of the
+# minor dim and no lane movement at all. Every shift is a Python constant
+# (the static mux selects of the TVE), so XLA fuses it too. The axis pads
+# to whole tiles; an axis of at most ``g`` x 128 codes pads only to a
+# multiple of 32, exactly like the dense stream.
 
 GROUP = 32
+LANES = 128
+# Name of this layout in persisted payloads: a checkpoint whose packed
+# leaves carry another (or no) layout tag was packed differently and must
+# not be decoded with these shifts.
+LAYOUT = "lane-tiled-128"
+
+
+def lane_group(width: int) -> Tuple[int, int]:
+    """(codes, words) of one lane group: the fewest ``width``-bit codes
+    that fill whole 32-bit words, and those words."""
+    _check_width(width)
+    g = 32 // math.gcd(width, 32)
+    return g, g * width // 32
+
+
+def tile_lanes(n: int, width: int) -> int:
+    """Lanes per chunk ``L`` for a packed axis of ``n`` codes."""
+    g, _ = lane_group(width)
+    n32 = -(-n // GROUP) * GROUP
+    if g == 1:                      # 32-bit codes: the identity layout
+        return n32
+    return min(LANES, n32 // g)
+
+
+def tile_codes(n: int, width: int) -> int:
+    """Codes per tile — the packed-axis alignment of every kernel block."""
+    return lane_group(width)[0] * tile_lanes(n, width)
 
 
 def packed_group_words(n: int, width: int) -> int:
-    """Packed last-dim length for ``n`` codes (padded to a full group)."""
-    _check_width(width)
-    groups = -(-n // GROUP)
-    return groups * width
+    """Packed last-dim length for ``n`` codes (padded to whole tiles)."""
+    g, wg = lane_group(width)
+    lanes = tile_lanes(n, width)
+    tiles = -(-n // (g * lanes))
+    return tiles * wg * lanes
+
+
+def shard_aligned(n: int, width: int, shards: int) -> bool:
+    """Whether a packed axis of ``n`` codes splits ``shards`` ways into
+    equal runs of whole tiles that keep the axis' own lane width — the
+    condition for every shard to be a self-contained lane-tiled array
+    whose logical slice matches the logical tensor's even split."""
+    if shards <= 1:
+        return True
+    if n % shards:
+        return False
+    local = n // shards
+    return (local % tile_codes(n, width) == 0
+            and tile_lanes(local, width) == tile_lanes(n, width))
+
+
+def _plane_terms(width: int):
+    """For code plane ``j`` of a lane group: (first word, bit offset,
+    spills into the next word). The one schedule both the XLA and the
+    in-kernel unpack read, so they cannot disagree on the layout."""
+    g, _ = lane_group(width)
+    return tuple((j * width // 32, j * width % 32,
+                  j * width % 32 + width > 32) for j in range(g))
+
+
+def _extract(lo, hi, off: int, spill: bool, width: int):
+    code = lo >> np.uint32(off)
+    if spill:
+        code = code | (hi << np.uint32(32 - off))
+    return code & _width_mask(width)
 
 
 def pack_groups(codes: jnp.ndarray, width: int) -> jnp.ndarray:
-    """Pack codes (..., N) -> words (..., N/32*width), group-of-32 layout."""
-    _check_width(width)
+    """Pack codes (..., N) -> words (..., packed_group_words(N)), lane-tiled."""
+    g, wg = lane_group(width)
     codes = jnp.asarray(codes, _U32) & _width_mask(width)
-    n = codes.shape[-1]
-    groups = -(-n // GROUP)
-    pad = groups * GROUP - n
+    lead, n = codes.shape[:-1], codes.shape[-1]
+    lanes = tile_lanes(n, width)
+    tiles = -(-n // (g * lanes))
+    pad = tiles * g * lanes - n
     if pad:
         codes = jnp.concatenate(
-            [codes, jnp.zeros(codes.shape[:-1] + (pad,), _U32)], axis=-1
-        )
-    g = codes.reshape(codes.shape[:-1] + (groups, GROUP))
+            [codes, jnp.zeros(lead + (pad,), _U32)], axis=-1)
+    c = codes.reshape(lead + (tiles, g, lanes))
     words = []
-    for w in range(width):
+    for w in range(wg):
         acc = None
-        for j in range(GROUP):
-            s = j * width
-            if s // 32 == w:                       # low part lands here
-                part = g[..., j] << np.uint32(s % 32)
-            elif s // 32 == w - 1 and s % 32 + width > 32:  # spill part
-                part = g[..., j] >> np.uint32(32 - s % 32)
+        for j, (w0, off, spill) in enumerate(_plane_terms(width)):
+            if w0 == w:                       # low part lands here
+                part = c[..., j, :] << np.uint32(off)
+            elif w0 == w - 1 and spill:       # spill part
+                part = c[..., j, :] >> np.uint32(32 - off)
             else:
                 continue
             acc = part if acc is None else acc | part
         words.append(acc)
-    out = jnp.stack(words, axis=-1)                # (..., groups, width)
-    return out.reshape(out.shape[:-2] + (groups * width,))
+    out = jnp.stack(words, axis=-2)           # (..., tiles, wg, lanes)
+    return out.reshape(lead + (tiles * wg * lanes,))
 
 
 def unpack_groups(words: jnp.ndarray, width: int, n: int) -> jnp.ndarray:
-    """Inverse of :func:`pack_groups`: words (..., G*width) -> codes (..., n)."""
-    _check_width(width)
+    """Inverse of :func:`pack_groups`: words (..., W) -> codes (..., n)."""
+    g, wg = lane_group(width)
     words = jnp.asarray(words, _U32)
-    groups = words.shape[-1] // width
-    g = words.reshape(words.shape[:-1] + (groups, width))
-    cols = []
-    for j in range(GROUP):
-        s = j * width
-        w0, off = s // 32, s % 32
-        lo = g[..., w0] >> np.uint32(off)
-        if off + width > 32:
-            lo = lo | (g[..., w0 + 1] << np.uint32(32 - off))
-        cols.append(lo & _width_mask(width))
-    out = jnp.stack(cols, axis=-1)                 # (..., groups, 32)
-    out = out.reshape(out.shape[:-2] + (groups * GROUP,))
-    return out[..., :n]
+    lead = words.shape[:-1]
+    lanes = tile_lanes(n, width)
+    tiles = words.shape[-1] // (wg * lanes)
+    w = words.reshape(lead + (tiles, wg, lanes))
+    cols = [_extract(w[..., w0, :], w[..., w0 + 1, :] if spill else None,
+                     off, spill, width)
+            for w0, off, spill in _plane_terms(width)]
+    out = jnp.stack(cols, axis=-2)            # (..., tiles, g, lanes)
+    return out.reshape(lead + (tiles * g * lanes,))[..., :n]
+
+
+def unpack_chunk(words, width: int, lanes: int, chunk: int):
+    """Codes ``[chunk*lanes, (chunk+1)*lanes)`` of a block of packed words
+    — the in-kernel unpack. ``words`` (a value or a ref) holds whole tiles
+    of ``lanes``-wide chunks along its last axis; the result comes from
+    static, chunk-aligned lane slices of one or two word chunks, shifted,
+    or-ed and masked as whole vregs (no reshape, no lane movement)."""
+    g, wg = lane_group(width)
+    t, j = divmod(chunk, g)
+    w0, off, spill = _plane_terms(width)[j]
+    base = (t * wg + w0) * lanes
+    lo = words[..., base:base + lanes]
+    hi = words[..., base + lanes:base + 2 * lanes] if spill else None
+    return _extract(lo, hi, off, spill, width)
+
+
+def unpack_block(words, width: int, lanes: int):
+    """Every code of a block of whole tiles, chunk by chunk
+    (:func:`unpack_chunk` concatenated along lanes)."""
+    g, wg = lane_group(width)
+    chunks = words.shape[-1] // (wg * lanes) * g
+    parts = [unpack_chunk(words, width, lanes, c) for c in range(chunks)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)

@@ -94,7 +94,7 @@ def float_leaves(
     tensors: Dict[str, jnp.ndarray] = {}
 
     def visit(path, leaf):
-        if (np.issubdtype(leaf.dtype, np.floating)
+        if (jnp.issubdtype(leaf.dtype, jnp.floating)
                 and getattr(leaf, "ndim", 0) >= min_ndim):
             tensors[path_str(path)] = leaf
 

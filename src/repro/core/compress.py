@@ -359,7 +359,7 @@ def uniform_plan(tree: Any, bits: int, min_ndim: int = 2) -> CompressionPlan:
             if leaf.kind == "float":
                 float_bits[path_str(path)] = bits
             return
-        if (np.issubdtype(leaf.dtype, np.floating)
+        if (jnp.issubdtype(leaf.dtype, jnp.floating)
                 and getattr(leaf, "ndim", 0) >= min_ndim):
             float_bits[path_str(path)] = bits
 

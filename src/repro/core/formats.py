@@ -249,41 +249,27 @@ def decode_float(code: jnp.ndarray, fmt: FloatFormat) -> jnp.ndarray:
     if fmt.total_bits == 32:
         return bits_to_f32(code)
 
-    e_t, m_t = fmt.exp_bits, fmt.mantissa_bits
-    sign = (code >> np.uint32(fmt.total_bits - 1)) & _ONE
-    exp = (code >> np.uint32(m_t)) & np.uint32(fmt.max_biased_exp)
-    man = code & np.uint32((1 << m_t) - 1)
+    m_t = fmt.mantissa_bits
+    sign = (code >> np.uint32(fmt.total_bits - 1)) << np.uint32(31)
+    mag = code & np.uint32((1 << (fmt.total_bits - 1)) - 1)  # exp | man
+    exp = mag >> np.uint32(m_t)
+    man = mag & np.uint32((1 << m_t) - 1)
 
-    is_special = exp == np.uint32(fmt.max_biased_exp)
-    is_zero = (exp == 0) & (man == 0)
-    is_sub = (exp == 0) & (man != 0)
+    # Normals: left-align exp|man under the f32 fields, then rebias the
+    # exponent with one add (no carry: the rebiased exponent stays < 255).
+    normal = (mag << np.uint32(23 - m_t)) + np.uint32((127 - fmt.bias) << 23)
+    # Subnormals and zeros: value = man * 2^(1 - bias - m_t), exact in f32
+    # (an integer below 2^m_t times a power of two that stays normal), so
+    # no leading-one search and no f32 subnormal the TPU would flush.
+    sub = f32_to_bits(man.astype(jnp.int32).astype(jnp.float32)
+                      * np.float32(2.0 ** (1 - fmt.bias - m_t)))
+    special = np.uint32(0x7F800000) | jnp.where(
+        man != 0, np.uint32(1 << 22), np.uint32(0))
 
-    # Normals: rebias exponent, left-align mantissa.
-    e32 = exp.astype(jnp.int32) - fmt.bias + 127
-    m32 = man << np.uint32(23 - m_t)
-
-    # Subnormals: value = man * 2^(1 - bias - m_t); normalize.
-    # Leading-one index via bit smearing + popcount (exact, unlike log2).
-    v = jnp.maximum(man, _ONE)        # guard man==0 lanes (masked out below)
-    for s in (1, 2, 4, 8, 16):
-        v = v | (v >> np.uint32(s))
-    top = jnp.bitwise_count(v).astype(_U32) - _ONE  # index of leading one
-    shift_up = np.uint32(23) - top
-    m_sub = (man << shift_up) & np.uint32(0x7FFFFF)  # drop implicit one
-    e_sub = (top.astype(jnp.int32) - m_t) + (1 - fmt.bias) + 127
-
-    e32 = jnp.where(is_sub, e_sub, e32)
-    m32 = jnp.where(is_sub, m_sub, m32)
-
-    e32 = jnp.where(is_special, 255, e32)
-    m32 = jnp.where(
-        is_special, jnp.where(man != 0, np.uint32(1 << 22), np.uint32(0)), m32
-    )
-    e32 = jnp.where(is_zero, 0, e32)
-    m32 = jnp.where(is_zero, np.uint32(0), m32)
-
-    out = (sign << np.uint32(31)) | (e32.astype(_U32) << np.uint32(23)) | m32
-    return bits_to_f32(out)
+    out = jnp.where(exp == 0, sub,
+                    jnp.where(exp == np.uint32(fmt.max_biased_exp),
+                              special, normal))
+    return bits_to_f32(out | sign)
 
 
 # ---------------------------------------------------------------------------

@@ -75,15 +75,40 @@ def occupancy(
 
 @dataclasses.dataclass(frozen=True)
 class TPUChipConfig:
-    """TPU v5e-class chip (the hardware constants of the roofline spec)."""
+    """One TPU chip's published constants (the roofline and residency
+    inputs). Defaults are TPU v5e's."""
 
     hbm_bytes: int = 16 * 1024**3
     peak_flops_bf16: float = 197e12
     hbm_bw: float = 819e9
     ici_bw_per_link: float = 50e9
+    source: str = ("Google Cloud TPU docs, 'TPU v5e': 197 TFLOP/s bf16, "
+                   "16 GiB HBM at 819 GB/s, 1600 Gbit/s ICI per chip")
 
 
 TPU_V5E = TPUChipConfig()
+
+# Chip constants keyed by ``jax.Device.device_kind``. The one table the
+# serving planner and the benchmarks read; a TPU kind missing here is an
+# error, never a silent default.
+CHIPS = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_device(device) -> TPUChipConfig:
+    """The constants of ``device``'s chip. A TPU whose ``device_kind`` is
+    not in :data:`CHIPS` raises. Off-TPU (CPU tests, dry-runs) there is
+    no chip to read, and the planner sizes for the reference TPU v5e."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no chip constants for TPU kind {device.device_kind!r}; add "
+            f"it to repro.core.occupancy.CHIPS (known: {sorted(CHIPS)})"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,11 +2,11 @@
 
 A ``PackedTensor`` is the framework's architectural-register analogue: a
 logical float or integer tensor stored as a dense uint32 bitstream in the
-group-of-32 layout of ``repro.core.bitpack`` with a statically assigned
+lane-tiled layout of ``repro.core.bitpack`` with a statically assigned
 bitwidth (from precision tuning / range analysis). It is a pytree node, so
 packed state flows through jit/pjit/grad machinery and can be sharded;
-the packed (last) axis shards evenly whenever the logical axis length is a
-multiple of 32 x shard-count.
+the packed (last) axis shards whenever every shard is whole lane tiles
+(``bitpack.shard_aligned``).
 
 A ``PackedStore`` is the indirection table analogue for a whole state
 pytree: per-leaf format metadata + packed payloads, with helpers to pack /
@@ -38,7 +38,7 @@ from repro.core.formats import (
 class PackedTensor:
     """A tensor packed along its last axis at ``bits`` per element."""
 
-    data: jnp.ndarray                # uint32 (..., groups*bits)
+    data: jnp.ndarray                # uint32 (..., packed_group_words)
     bits: int                        # total bits per element (mult of 4)
     kind: str                        # "float" | "int"
     signed: bool                     # int decode extension mode
@@ -181,7 +181,7 @@ def pack_tensor(
 ) -> PackedTensor:
     x = jnp.asarray(x)
     if kind is None:
-        kind = "float" if np.issubdtype(x.dtype, np.floating) else "int"
+        kind = "float" if jnp.issubdtype(x.dtype, jnp.floating) else "int"
     out_dtype = out_dtype or x.dtype
     if kind == "float":
         codes = encode_float(x.astype(jnp.float32), FLOAT_FORMATS[bits])
@@ -280,7 +280,7 @@ def weight_pass_bytes(tree: Any) -> Dict[str, int]:
     path: ``fused`` (packed leaves, the bytes the fused kernels read),
     ``fused_f32`` (what those leaves would cost dense f32),
     ``analytic`` (the paper's bits/32 model summed per leaf — no
-    group-of-32 padding, the reference the live telemetry byte counters
+    lane-tile padding, the reference the live telemetry byte counters
     are held to within ``obs.schema.BYTE_TOLERANCE``), and ``dense``
     (plain leaves: norms, biases, unpacked weights)."""
     fused = fused_f32 = analytic = dense = 0

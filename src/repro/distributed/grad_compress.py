@@ -54,7 +54,8 @@ def ring_reduce_codes(
     """All-reduce(sum) of ``x`` over ``axis_name`` moving only codes.
 
     Call inside shard_map with the DP axis manual. Requires len(x) to be
-    divisible by D*32.
+    divisible by D*32. Each chunk packs (and decodes) on its own, so
+    chunks travel as self-contained lane-tiled payloads.
     """
     d = axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
@@ -99,8 +100,7 @@ def ring_reduce_codes(
     for ci, codes in gathered:
         onehot = (jnp.arange(d) == ci).astype(jnp.uint32)[:, None]
         out = out + onehot * codes[None, :]
-    decoded = _decode(out.reshape(-1), bits, n)
-    return decoded
+    return _decode(out, bits, chunk).reshape(-1)
 
 
 def compressed_psum(

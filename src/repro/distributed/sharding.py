@@ -8,8 +8,7 @@ same model code serves TP (replicated weights across DP) and ZeRO
 ``with_sharding_constraint`` that degrades to a no-op outside any mesh.
 
 All mesh-context queries go through ``repro.compat`` — the one layer
-that knows whether this jax serves them from the abstract mesh (>=0.5)
-or the legacy ``thread_resources`` context (0.4.x).
+that knows how this jax exposes the current mesh.
 """
 from __future__ import annotations
 
@@ -170,25 +169,25 @@ def _spec_shards(entry, sizes: Dict[str, int]) -> int:
 
 
 def spec_for_packed(path: str, logical_shape: Tuple[int, ...],
-                    mode: str = "tp",
+                    bits: int, mode: str = "tp",
                     axis_sizes: Optional[Dict[str, int]] = None) -> P:
     """PartitionSpec for a packed uint32 word array, consistent with the
     *logical* tensor's spec.
 
     The payload has the logical rank with the last axis rescaled to
-    group-of-32 words, so leading dims take the logical rules verbatim.
-    The packed (last) axis is the subtle one: an even word split that
-    lands mid-group would hand two devices halves of one group's
-    shift/or network — checking word divisibility alone is wrong (e.g.
-    96 codes at AF16 = 48 words split 2 ways is 24 words each but 1.5
-    groups). And a split on a group boundary is still wrong when the
-    last group carries padding (48 codes = 2 groups: a 2-way group split
-    gives device 0 logical codes 0-31 and device 1 codes 32-47 + pad,
-    misaligned with the 24/24 logical split every logical-spec consumer
-    assumes). The axis may shard only when the *logical* axis is a
-    multiple of 32 x shard-count; otherwise it drops to replicated.
+    lane-tiled words (``core.bitpack``), so leading dims take the logical
+    rules verbatim. The packed (last) axis is the subtle one: an even
+    word split that lands mid-tile would hand two devices halves of one
+    tile's shift/or network — checking word divisibility alone is wrong
+    (e.g. 96 codes at AF16 = 48 words split 2 ways is 24 words each, but
+    the whole axis is one tile). A split on a tile boundary is still
+    wrong when the last tile carries padding (misaligned with the even
+    logical split every logical-spec consumer assumes), or when a shard
+    is short enough that it would lay its codes out on narrower lanes.
+    The axis may shard only when ``bitpack.shard_aligned`` holds;
+    otherwise it drops to replicated.
 
-    ``axis_sizes`` overrides the current-mesh query for the group check
+    ``axis_sizes`` overrides the current-mesh query for the tile check
     (unit-testable without a multi-device mesh, like
     ``drop_indivisible``)."""
     from repro.core import bitpack
@@ -200,18 +199,18 @@ def spec_for_packed(path: str, logical_shape: Tuple[int, ...],
         sizes = (axis_sizes if axis_sizes is not None
                  else current_mesh_axis_sizes())
         shards = _spec_shards(entries[-1], sizes)
-        if shards > 1 and logical_shape[-1] % (bitpack.GROUP * shards):
+        if not bitpack.shard_aligned(logical_shape[-1], bits, shards):
             entries[-1] = None
     return P(*entries)
 
 
 def shard_leaf(path: str, leaf, mesh: Mesh, mode: str = "tp"):
     """NamedSharding for one (possibly packed) parameter leaf. Packed
-    leaves shard by their *logical* spec with the group-of-32 word axis
+    leaves shard by their *logical* spec with the lane-tiled word axis
     kept intact (``spec_for_packed``) — never by raw payload shape, which
-    can split a group across devices."""
+    can split a tile across devices."""
     from repro.core.tensor_store import PackedTensor
     if isinstance(leaf, PackedTensor):
         return NamedSharding(mesh, spec_for_packed(
-            path, leaf.logical_shape, mode))
+            path, leaf.logical_shape, leaf.bits, mode))
     return NamedSharding(mesh, spec_for(path, leaf.shape, mode))

@@ -6,11 +6,16 @@ at the statically tuned width cuts the dominant roofline term by bits/32
 *and* lets proportionally more sequences stay resident (serving
 "occupancy", see core/occupancy.decode_residency).
 
-One grid step processes one (batch, kv-head) pair and one sequence chunk:
-K/V chunks are unpacked in VMEM (Value Extractor path), the chunk's
-contribution to the online softmax is accumulated in f32 VMEM scratch
-(running max / normalizer / weighted values — flash-decoding style), and
-the final grid step normalizes and writes the (group, D) output tile.
+The cache keeps one token's K (or V) row for every KV head as one packed
+row, ``(B, S, packed_group_words(Hkv * D))``, so a sequence chunk is a
+``(block_s, words)`` tile whose minor dim is whole 128-lane chunks at
+real widths. One grid step processes one sequence and one chunk for all
+KV heads: each head's codes are whole lane chunks of the tile
+(``kv_head_codes``), unpacked in VMEM (Value Extractor path), and the
+chunk's contribution to the online softmax is accumulated in f32 VMEM
+scratch (running max / normalizer / weighted values — flash-decoding
+style). The valid lengths arrive on the scalar-prefetch path (SMEM), and
+the final grid step normalizes and writes the (Hkv, group, D) output.
 """
 from __future__ import annotations
 
@@ -29,10 +34,59 @@ DEFAULT_BLOCK_S = 512
 NEG_INF = -1e30
 
 
+def kv_head_rows(kv, h: int, d: int, bits: int, hkv: int) -> jnp.ndarray:
+    """Head ``h``'s (rows, D) f32 K/V values out of a block of packed
+    cache rows (``bits`` > 0) or dense rows (``bits`` == 0). At real
+    widths a head is whole lane chunks of the tile, so only its own chunks
+    unpack; tiny test widths whose heads split a chunk unpack the block
+    and slice."""
+    if not bits:
+        return kv[:, h * d:(h + 1) * d].astype(jnp.float32)
+    lanes = bitpack.tile_lanes(hkv * d, bits)
+    if d % lanes == 0:
+        per = d // lanes
+        parts = [bitpack.unpack_chunk(kv, bits, lanes, c)
+                 for c in range(h * per, (h + 1) * per)]
+        codes = parts[0] if per == 1 else jnp.concatenate(parts, axis=-1)
+    else:
+        codes = bitpack.unpack_block(kv, bits, lanes)[:, h * d:(h + 1) * d]
+    return decode_float(codes, FLOAT_FORMATS[bits])
+
+
+def online_softmax_step(q_ref, k, v, h: int, m_ref, l_ref, acc_ref,
+                        valid) -> None:
+    """Fold one KV chunk of head ``h`` into the running (m, l, acc)."""
+    d = q_ref.shape[-1]
+    q = q_ref[0, h].astype(jnp.float32)                   # (G, D)
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),                   # q @ k.T
+        preferred_element_type=jnp.float32) * (1.0 / (d ** 0.5))
+    logits = jnp.where(valid, logits, NEG_INF)            # (G, rows)
+    m_prev = m_ref[h]                                     # (G, 1)
+    m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
+    scale = jnp.exp(m_prev - m_new)
+    p = jnp.exp(logits - m_new)
+    l_ref[h] = l_ref[h] * scale + p.sum(-1, keepdims=True)
+    acc_ref[h] = acc_ref[h] * scale + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_ref[h] = m_new
+
+
+def flush_heads(o_ref, m_ref, l_ref, acc_ref) -> None:
+    """Normalize and write every head. A fully masked sequence
+    (kv_len == 0) leaves m == NEG_INF and l with garbage mass: guard the
+    normalizer and emit zeros for those rows."""
+    empty = m_ref[...] <= NEG_INF * 0.5                   # (Hkv, G, 1)
+    l_safe = jnp.maximum(l_ref[...], 1e-30)
+    out = jnp.where(empty, 0.0, acc_ref[...] / l_safe)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
 def _kv_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                       m_ref, l_ref, acc_ref,
-                      *, bits: int, d: int, block_s: int, s_steps: int):
-    s_idx = pl.program_id(2)
+                      *, bits: int, hkv: int, block_s: int, s_steps: int):
+    b, s_idx = pl.program_id(0), pl.program_id(1)
+    d = q_ref.shape[-1]
 
     @pl.when(s_idx == 0)
     def _init():
@@ -40,100 +94,74 @@ def _kv_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                   # (G, D)
-    k_codes = bitpack.unpack_groups(k_ref[0, 0], bits, d)  # (S_blk, D)
-    k = decode_float(k_codes, FLOAT_FORMATS[bits])
-    v_codes = bitpack.unpack_groups(v_ref[0, 0], bits, d)
-    v = decode_float(v_codes, FLOAT_FORMATS[bits])
-
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    logits = logits * (1.0 / (d ** 0.5))                  # (G, S_blk)
-
-    # mask beyond the sequence's valid length
-    base = s_idx * block_s
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    valid = pos < len_ref[0]
-    logits = jnp.where(valid, logits, NEG_INF)
-
-    m_prev = m_ref[...]                                   # (G, 1)
-    m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
-    scale = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)                           # (G, S_blk)
-    l_ref[...] = l_ref[...] * scale + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * scale + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    # chunks wholly past the valid length contribute nothing: skip them
+    @pl.when(s_idx * block_s < len_ref[b])
+    def _accumulate():
+        kw, vw = k_ref[0], v_ref[0]                       # (block_s, W)
+        pos = s_idx * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[2], block_s), 1)
+        valid = pos < len_ref[b]
+        for h in range(hkv):
+            online_softmax_step(q_ref,
+                                kv_head_rows(kw, h, d, bits, hkv),
+                                kv_head_rows(vw, h, d, bits, hkv),
+                                h, m_ref, l_ref, acc_ref, valid)
 
     @pl.when(s_idx == s_steps - 1)
     def _flush():
-        # A fully masked sequence (kv_len == 0) leaves m == NEG_INF and
-        # p == exp(0) == 1 for every masked position, so l accumulates
-        # garbage mass and acc / l would emit the mean of stale cache
-        # rows. Guard the normalizer (flash_attention's maximum(l, eps))
-        # and mask the degenerate rows to zeros explicitly.
-        empty = m_ref[...] <= NEG_INF * 0.5               # (G, 1)
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        out = jnp.where(empty, 0.0, acc_ref[...] / l_safe)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        flush_heads(o_ref, m_ref, l_ref, acc_ref)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bits", "d", "block_s", "interpret"),
+    static_argnames=("bits", "hkv", "block_s", "interpret"),
 )
 def kv_decode(
     q: jnp.ndarray,            # (B, H, D)
-    k_packed: jnp.ndarray,     # (B, S, Hkv, D*bits/32) uint32
-    v_packed: jnp.ndarray,     # (B, S, Hkv, D*bits/32) uint32
+    k_packed: jnp.ndarray,     # (B, S, packed_group_words(Hkv*D)) uint32
+    v_packed: jnp.ndarray,     # (B, S, packed_group_words(Hkv*D)) uint32
     kv_len: jnp.ndarray,       # (B,) int32 valid lengths
     bits: int,
-    d: int,
+    hkv: int,
     block_s: int = DEFAULT_BLOCK_S,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
+    from jax.experimental.pallas import tpu as pltpu
+
     interpret = pallas_interpret_default(interpret)
     b, h, dim = q.shape
-    s, hkv = k_packed.shape[1], k_packed.shape[2]
+    s, words = k_packed.shape[1], k_packed.shape[2]
     group = h // hkv
     block_s = min(block_s, s)
     assert s % block_s == 0
     s_steps = s // block_s
-    dw = dim // 32 * bits
 
-    # (B, Hkv, G, D) view of q so one grid step owns one kv head's group.
+    # (B, Hkv, G, D) view of q: one block holds every head's group
     qg = q.reshape(b, hkv, group, dim)
-    # (B, Hkv, S, Dw) views of the packed caches.
-    kp = jnp.swapaxes(k_packed, 1, 2)
-    vp = jnp.swapaxes(v_packed, 1, 2)
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, dim), jnp.float32),
-        ]
-    except ImportError:  # pragma: no cover
-        scratch = []
-
-    grid = (b, hkv, s_steps)
-    out = pl.pallas_call(
-        functools.partial(
-            _kv_decode_kernel, bits=bits, d=dim, block_s=block_s,
-            s_steps=s_steps,
-        ),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s_steps),
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, js: (ib,)),
-            pl.BlockSpec((1, 1, group, dim), lambda ib, ih, js: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, dw), lambda ib, ih, js: (ib, ih, js, 0)),
-            pl.BlockSpec((1, 1, block_s, dw), lambda ib, ih, js: (ib, ih, js, 0)),
+            pl.BlockSpec((1, hkv, group, dim),
+                         lambda ib, js, lens: (ib, 0, 0, 0)),
+            pl.BlockSpec((1, block_s, words),
+                         lambda ib, js, lens: (ib, js, 0)),
+            pl.BlockSpec((1, block_s, words),
+                         lambda ib, js, lens: (ib, js, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, dim),
-                               lambda ib, ih, js: (ib, ih, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, group, dim),
+                               lambda ib, js, lens: (ib, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, dim), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kv_decode_kernel, bits=bits, hkv=hkv,
+                          block_s=block_s, s_steps=s_steps),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, dim), q.dtype),
-        scratch_shapes=scratch,
         interpret=interpret,
-    )(kv_len, qg, kp, vp)
+    )(kv_len.astype(jnp.int32), qg, k_packed, v_packed)
     return out.reshape(b, h, dim)

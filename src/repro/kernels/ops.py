@@ -122,19 +122,18 @@ def record_dispatch(op: str, path: str, packed_bytes: int = 0,
 
 
 def unpack(packed, bits: int, n: int, out_dtype=jnp.float32):
+    """Materialized unpack + decode. XLA is the only implementation: the
+    lane-tiled shifts fuse into the consumer, where a standalone kernel
+    would only add an HBM round trip of the decoded tensor."""
     record_dispatch("unpack", "materialized", packed.size * 4,
                     shape=packed.shape[:-1] + (n,), bits=bits)
-    if BACKEND.use_pallas and packed.ndim == 2:
-        from repro.kernels.unpack import unpack as _k
-        return _k(packed, bits, n, out_dtype, interpret=BACKEND.interpret)
     return _ref.unpack_ref(packed, bits, n, out_dtype)
 
 
 def pack(x, bits: int):
+    """Encode + pack along the last axis (the KV append and the weight
+    packer). XLA only, for the same reason as :func:`unpack`."""
     record_dispatch("pack", "encode")
-    if BACKEND.use_pallas and x.ndim == 2:
-        from repro.kernels.pack import pack as _k
-        return _k(x, bits, interpret=BACKEND.interpret)
     return _ref.pack_ref(x, bits)
 
 
@@ -194,39 +193,43 @@ def packed_matmul_dw(x, g, transpose: bool = False, batched: bool = False):
     return _ref.packed_matmul_dw_ref(x, g, transpose, batched)
 
 
-def kv_decode(q, k_packed, v_packed, kv_len, bits: int, d: int):
+def kv_decode(q, k_packed, v_packed, kv_len, bits: int, hkv: int):
+    """One-token attention over packed KV caches (B, S, words): one
+    packed row per token holds all ``hkv`` heads."""
     record_dispatch("kv_decode", "kv_decode",
                     (k_packed.size + v_packed.size) * 4,
                     shape=k_packed.shape, bits=bits)
     if BACKEND.use_pallas:
         from repro.kernels.kv_decode import kv_decode as _k
-        return _k(q, k_packed, v_packed, kv_len, bits, d,
+        return _k(q, k_packed, v_packed, kv_len, bits, hkv,
                   interpret=BACKEND.interpret)
-    return _ref.kv_decode_ref(q, k_packed, v_packed, bits, d, kv_len)
+    return _ref.kv_decode_ref(q, k_packed, v_packed, bits, hkv, kv_len)
 
 
-def paged_attention(q, k_pool, v_pool, table, kv_len, bits: int, d: int,
+def paged_attention(q, k_pool, v_pool, table, kv_len, bits: int, hkv: int,
                     fallback: bool = False):
     """Attend one token straight through the page table (the fused paged
-    serving hot path): pools (P+1, page, Hkv, W) packed words (or dense
-    rows when ``bits`` is 0), table (B, max_pages) int32 page ids. Only
-    the pages the table names leave HBM — the dense gathered view never
-    materializes. ``fallback=True`` is the parity escape hatch: it runs
-    the gather-materialize oracle instead and records itself as such, so
-    the dispatch linter can tell a deliberate oracle run from a fused
-    path that silently de-fused. ``packed_bytes`` stays 0 on the fused
+    serving hot path): pools (P+1, page, words) of packed rows holding
+    all ``hkv`` heads (or (P+1, page, Hkv*D) dense rows when ``bits`` is
+    0), table (B, max_pages) int32 page ids. Only the pages the table
+    names leave HBM — the dense gathered view never materializes.
+    ``fallback=True`` is the parity escape hatch: it runs the
+    gather-materialize oracle instead and records itself as such, so the
+    dispatch linter can tell a deliberate oracle run from a fused path
+    that silently de-fused. ``packed_bytes`` stays 0 on the fused
     record: bytes-read scale with pages actually live, which only the
-    serving layer knows (``kv_pages_read`` counters), not the pool size."""
+    serving layer knows (``kv_pages_read`` counters), not the pool
+    size."""
     if fallback:
         record_dispatch("paged_attention", "materialized",
                         shape=k_pool.shape, bits=bits)
         return _ref.paged_attention_ref(q, k_pool, v_pool, table, kv_len,
-                                        bits, d)
+                                        bits, hkv)
     record_dispatch("paged_attention", "fused_paged",
                     shape=k_pool.shape, bits=bits)
     if BACKEND.use_pallas:
         from repro.kernels.paged_attention import paged_attention as _k
-        return _k(q, k_pool, v_pool, table, kv_len, bits, d,
+        return _k(q, k_pool, v_pool, table, kv_len, bits, hkv,
                   interpret=BACKEND.interpret)
     return _ref.paged_attention_ref(q, k_pool, v_pool, table, kv_len,
-                                    bits, d)
+                                    bits, hkv)

@@ -25,18 +25,20 @@ it accepts everything the model stack throws at it:
     same last-axis x first-axis contraction;
   * bf16 or f32 ``x`` (tiles upcast to f32 on the VPU; the MXU dot
     accumulates f32; output is ``out_dtype``, defaulting to ``x.dtype``);
-  * non-multiple M/N/K: each grid axis picks the largest aligned divisor
-    block <= the target (the trace-time search of
-    ``flash_attention._divisor_chunk``); when no divisor is MXU-viable
-    (best divisor under 1/8 of the target — e.g. a prime dim) the axis is
-    zero-padded up to a block multiple instead. Zero-padded packed words
-    decode to +0.0 and padded x rows/cols are zeros, so padding never
-    changes the contraction; outputs are sliced back to logical shape.
+  * non-multiple M/N/K: an unpacked axis no longer than its target is one
+    full-dim block; a longer one picks the largest divisor block <= the
+    target that is a multiple of 8 (sublane axes) or 128 (lane axes);
+    when no divisor is MXU-viable (best divisor under 1/8 of the target —
+    e.g. a prime dim) the axis is zero-padded up to a block multiple
+    instead. Padded x rows/cols are zeros, so padding never changes the
+    contraction; outputs are sliced back to logical shape.
 
 Grid is (M/bm, N/bn, K/bk) with the K dimension innermost ("arbitrary"
-semantics) accumulating into a VMEM f32 scratch; MXU-aligned bm/bn
-multiples of 128 and group-aligned packed-axis blocks (multiples of 32
-codes, a layout constraint of ``bitpack.pack_groups``).
+semantics) accumulating into a VMEM f32 scratch. Packed-axis blocks are
+whole tiles of ``bitpack``'s lane-tiled layout (a multiple of 128 words
+at real widths), so the in-kernel unpack is whole-vreg shifts and masks
+(``bitpack.unpack_block``) and the packed axis never pads: its words
+already cover whole tiles, and tile padding decodes to +0.0.
 
 ``interpret=None`` resolves through ``repro.compat.pallas``: compiled on
 a real TPU, interpret (Python validation) everywhere else.
@@ -71,21 +73,25 @@ from repro.core import bitpack
 from repro.core.formats import FLOAT_FORMATS, decode_float
 
 DEFAULT_BM = 128
-DEFAULT_BN = 256
+DEFAULT_BN = 512
 DEFAULT_BK = 512
+SUBLANES = 8
 
 
 def _plan_axis(dim: int, target: int, align: int) -> Tuple[int, int]:
-    """Choose (block, padded_dim) for one grid axis.
+    """Choose (block, padded_dim) for one unpacked grid axis.
 
-    Prefer the largest divisor of ``dim`` that is <= ``target`` and a
-    multiple of ``align``; if the best such divisor is under 1/8 of the
-    achievable target (no MXU-viable divisor, e.g. a large prime dim),
+    An axis no longer than ``target`` is one full-dim block (always a
+    legal TPU block). Otherwise prefer the largest divisor of ``dim`` that
+    is <= ``target`` and a multiple of ``align`` (8 sublanes or 128
+    lanes, whichever the axis lands on); if the best such divisor is under
+    1/8 of the target (no MXU-viable divisor, e.g. a large prime dim),
     fall back to an aligned ``target``-sized block and zero-pad the axis
     up to a multiple of it.
     """
-    cap = max(align, min(target, dim))
-    cap -= cap % align
+    if dim <= target:
+        return dim, dim
+    cap = max(align, target - target % align)
     best = 0
     for cand in range(cap, align - 1, -align):
         if dim % cand == 0:
@@ -94,6 +100,18 @@ def _plan_axis(dim: int, target: int, align: int) -> Tuple[int, int]:
     if best and best * 8 >= cap:
         return best, dim
     return cap, -(-dim // cap) * cap
+
+
+def _plan_packed(codes: int, target: int, tile: int) -> int:
+    """Block length along the packed axis: the largest whole number of
+    tiles that divides the (tile-padded) axis and is <= ``target`` — at
+    least one tile. The packed axis never pads: its words already cover
+    whole tiles."""
+    tiles = codes // tile
+    per = max(1, min(tiles, target // tile))
+    while tiles % per:
+        per -= 1
+    return per * tile
 
 
 def _pad_to(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
@@ -105,51 +123,106 @@ def _pad_to(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-def _pmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, bn: int,
-                k_steps: int):
-    k = pl.program_id(2)
+def _pmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, lanes: int,
+                k_steps: int, transpose: bool, batched: bool):
+    k = pl.program_id(3 if batched else 2)
+    sel = (0,) if batched else ()
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = bitpack.unpack_groups(w_ref[...], bits, bn)
-    w = decode_float(codes, FLOAT_FORMATS[bits])          # (bk, bn) f32
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    codes = bitpack.unpack_block(w_ref[sel], bits, lanes)
+    w = decode_float(codes, FLOAT_FORMATS[bits])   # (bk, bn) | (bn, bk)
+    x = x_ref[sel].astype(jnp.float32)             # (bm, bk)
+    if transpose:
+        acc_ref[...] += jax.lax.dot_general(
+            x, w, (((1,), (1,)), ((), ())),         # x @ w.T
+            preferred_element_type=jnp.float32,
+        )
+    else:
+        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
     def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[sel] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _pmm_t_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, bk: int,
-                  k_steps: int):
-    k = pl.program_id(2)
+def _packed_call(x3, w3, bits, n, transpose, bm, bn, bk, out_dtype,
+                 interpret):
+    """The fused call both entry points share: x3 (M, K) with w3
+    (K, Nw) — or (N, Kw) when ``transpose`` — for the 2-D kernel, and the
+    same with a leading expert axis on all three for the batched one."""
+    from jax.experimental.pallas import tpu as pltpu
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    batched = x3.ndim == 3
+    m, kdim = x3.shape[-2:]
+    packed_len = kdim if transpose else n
+    lanes = bitpack.tile_lanes(packed_len, bits)
+    tile = bitpack.tile_codes(packed_len, bits)
+    tile_words = bitpack.lane_group(bits)[1] * lanes
+    assert w3.shape[-1] == bitpack.packed_group_words(packed_len, bits), (
+        w3.shape, packed_len, bits)
+    codes_pad = w3.shape[-1] // tile_words * tile
 
-    codes = bitpack.unpack_groups(w_ref[...], bits, bk)
-    w = decode_float(codes, FLOAT_FORMATS[bits])          # (bn, bk) f32
-    x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),                   # x @ w.T
-        preferred_element_type=jnp.float32,
-    )
+    def words_of(codes):
+        return codes // tile * tile_words
 
-    @pl.when(k == k_steps - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    if transpose:
+        # W logical (N, K) packed along K: contraction over the packed
+        # axis, so K blocks cut on tile boundaries; N lands on the lanes
+        # of the output and the sublanes of the weight block.
+        assert w3.shape[-2] == n, (w3.shape, n)
+        bn_, n_pad = _plan_axis(n, bn, 128)
+        bk_ = _plan_packed(codes_pad, bk, tile)
+        k_pad = codes_pad
+        w3 = _pad_to(w3, w3.ndim - 2, n_pad)
+        w_block = (bn_, words_of(bk_))
+        w_index = lambda j, k: (j, k)
+    else:
+        # W logical (K, N) packed along N: output blocks cut on tile
+        # boundaries; K lands on the lanes of x and the sublanes of W.
+        assert w3.shape[-2] == kdim, (w3.shape, kdim)
+        bn_ = _plan_packed(codes_pad, bn, tile)
+        n_pad = codes_pad
+        bk_, k_pad = _plan_axis(kdim, bk, 128)
+        w3 = _pad_to(w3, w3.ndim - 2, k_pad)
+        w_block = (bk_, words_of(bn_))
+        w_index = lambda j, k: (k, j)
 
-
-def _vmem_scratch(bm: int, bn: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return [pltpu.VMEM((bm, bn), jnp.float32)]
-    except ImportError:  # pragma: no cover
-        return [pl.MemorySpace.ANY((bm, bn), jnp.float32)]
+    bm_, m_pad = _plan_axis(m, bm, SUBLANES)
+    x3 = _pad_to(_pad_to(x3, x3.ndim - 1, k_pad), x3.ndim - 2, m_pad)
+    k_steps = k_pad // bk_
+    grid = (m_pad // bm_, n_pad // bn_, k_steps)
+    kernel = functools.partial(_pmm_kernel, bits=bits, lanes=lanes,
+                               k_steps=k_steps, transpose=transpose,
+                               batched=batched)
+    if batched:
+        e = x3.shape[0]
+        grid = (e,) + grid
+        in_specs = [
+            pl.BlockSpec((1, bm_, bk_), lambda e_, i, j, k: (e_, i, k)),
+            pl.BlockSpec((1,) + w_block,
+                         lambda e_, i, j, k: (e_,) + w_index(j, k)),
+        ]
+        out_spec = pl.BlockSpec((1, bm_, bn_),
+                                lambda e_, i, j, k: (e_, i, j))
+        out_shape = (e, m_pad, n_pad)
+    else:
+        in_specs = [pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
+                    pl.BlockSpec(w_block, lambda i, j, k: w_index(j, k))]
+        out_spec = pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j))
+        out_shape = (m_pad, n_pad)
+    out = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
+        interpret=interpret,
+    )(x3, w3)
+    return out[..., :m, :n]
 
 
 @functools.partial(
@@ -159,8 +232,8 @@ def _vmem_scratch(bm: int, bn: int):
 )
 def packed_matmul(
     x: jnp.ndarray,            # (..., K) f32/bf16
-    w_packed: jnp.ndarray,     # (K, ceil(N/32)*bits) uint32, or
-                               # (N, ceil(K/32)*bits) when transpose
+    w_packed: jnp.ndarray,     # (K, packed_group_words(N)) uint32, or
+                               # (N, packed_group_words(K)) when transpose
     bits: int,
     n: int,                    # logical output features N
     transpose: bool = False,
@@ -173,103 +246,24 @@ def packed_matmul(
     """x @ W (or x @ W.T when ``transpose``) without materializing W.
 
     ``bm``/``bn``/``bk`` are block-size *targets*; the actual blocks come
-    from ``_plan_axis`` (divisor selection + padding fallback). ``n`` is
-    the logical output width — packed columns beyond it (group padding)
-    decode to zero and are sliced off.
+    from ``_plan_axis`` / ``_plan_packed``. ``n`` is the logical output
+    width — packed columns beyond it (tile padding) decode to zero and
+    are sliced off.
     """
     interpret = pallas_interpret_default(interpret)
     out_dtype = out_dtype or x.dtype
     assert w_packed.ndim == 2, "packed weights are 2-D (pack axis last)"
     assert bits in FLOAT_FORMATS, f"no float format with {bits} bits"
-
     lead = x.shape[:-1]
-    kdim = x.shape[-1]
     m = math.prod(lead) if lead else 1
-    x2 = x.reshape(m, kdim)
-
-    if transpose:
-        # W logical (N, K) packed along K; contraction over the packed
-        # axis, so K blocks must cut on 32-code group boundaries.
-        assert w_packed.shape[0] == n, (w_packed.shape, n)
-        k_ceil = w_packed.shape[1] // bits * bitpack.GROUP
-        assert kdim <= k_ceil
-        bn_, n_pad = _plan_axis(n, bn, 1)
-        bk_, k_pad = _plan_axis(k_ceil, bk, bitpack.GROUP)
-        wp = _pad_to(_pad_to(w_packed, 1, k_pad // 32 * bits), 0, n_pad)
-        kernel = functools.partial(_pmm_t_kernel, bits=bits, bk=bk_)
-        w_spec = pl.BlockSpec((bn_, bk_ // 32 * bits),
-                              lambda i, j, k: (j, k))
-    else:
-        # W logical (K, N) packed along N; output blocks must cut on
-        # group boundaries.
-        assert w_packed.shape[0] == kdim, (w_packed.shape, kdim)
-        n_ceil = w_packed.shape[1] // bits * bitpack.GROUP
-        assert n <= n_ceil
-        bn_, n_pad = _plan_axis(n_ceil, bn, bitpack.GROUP)
-        bk_, k_pad = _plan_axis(kdim, bk, 1)
-        wp = _pad_to(_pad_to(w_packed, 1, n_pad // 32 * bits), 0, k_pad)
-        kernel = functools.partial(_pmm_kernel, bits=bits, bn=bn_)
-        w_spec = pl.BlockSpec((bk_, bn_ // 32 * bits),
-                              lambda i, j, k: (k, j))
-
-    bm_, m_pad = _plan_axis(m, bm, 1)
-    x2 = _pad_to(_pad_to(x2, 1, k_pad), 0, m_pad)
-    k_steps = k_pad // bk_
-    out = pl.pallas_call(
-        functools.partial(kernel, k_steps=k_steps),
-        grid=(m_pad // bm_, n_pad // bn_, k_steps),
-        in_specs=[pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)), w_spec],
-        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype),
-        scratch_shapes=_vmem_scratch(bm_, bn_),
-        interpret=interpret,
-    )(x2, wp)
-
-    return out[:m, :n].reshape(lead + (n,))
+    out = _packed_call(x.reshape(m, x.shape[-1]), w_packed, bits, n,
+                       transpose, bm, bn, bk, out_dtype, interpret)
+    return out.reshape(lead + (n,))
 
 
 # ---------------------------------------------------------------------------
 # Batched-expert orientation: grid over a leading expert axis
 # ---------------------------------------------------------------------------
-
-def _bmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, bn: int,
-                k_steps: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    codes = bitpack.unpack_groups(w_ref[0], bits, bn)
-    w = decode_float(codes, FLOAT_FORMATS[bits])          # (bk, bn) f32
-    x = x_ref[0].astype(jnp.float32)                      # (bm, bk)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
-
-    @pl.when(k == k_steps - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _bmm_t_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, bk: int,
-                  k_steps: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    codes = bitpack.unpack_groups(w_ref[0], bits, bk)
-    w = decode_float(codes, FLOAT_FORMATS[bits])          # (bn, bk) f32
-    x = x_ref[0].astype(jnp.float32)                      # (bm, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),                   # x @ w.T
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k == k_steps - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
 
 @functools.partial(
     jax.jit,
@@ -278,8 +272,8 @@ def _bmm_t_kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, bk: int,
 )
 def packed_matmul_batched(
     x: jnp.ndarray,            # (E, C, K) f32/bf16
-    w_packed: jnp.ndarray,     # (E, K, ceil(N/32)*bits) uint32, or
-                               # (E, N, ceil(K/32)*bits) when transpose
+    w_packed: jnp.ndarray,     # (E, K, packed_group_words(N)) uint32, or
+                               # (E, N, packed_group_words(K)) if transpose
     bits: int,
     n: int,                    # logical output features N (per expert)
     transpose: bool = False,
@@ -296,9 +290,8 @@ def packed_matmul_batched(
     innermost for the scratch accumulation — and each block spec carries
     the expert coordinate, so an expert's packed words expand in VMEM only
     while that expert's grid slice is resident. Block planning per expert
-    is identical to the 2-D kernel (divisor selection, zero-pad fallback,
-    group-of-32-aligned packed-axis blocks); experts share one plan since
-    the bank is homogeneous.
+    is identical to the 2-D kernel; experts share one plan since the bank
+    is homogeneous.
     """
     interpret = pallas_interpret_default(interpret)
     out_dtype = out_dtype or x.dtype
@@ -306,50 +299,5 @@ def packed_matmul_batched(
     assert bits in FLOAT_FORMATS, f"no float format with {bits} bits"
     assert x.ndim == 3 and x.shape[0] == w_packed.shape[0], (
         x.shape, w_packed.shape)
-
-    e = x.shape[0]
-    m, kdim = x.shape[1], x.shape[2]
-
-    if transpose:
-        # W logical (E, N, K) packed along K; contraction over the packed
-        # axis — K blocks cut on 32-code group boundaries.
-        assert w_packed.shape[1] == n, (w_packed.shape, n)
-        k_ceil = w_packed.shape[2] // bits * bitpack.GROUP
-        assert kdim <= k_ceil
-        bn_, n_pad = _plan_axis(n, bn, 1)
-        bk_, k_pad = _plan_axis(k_ceil, bk, bitpack.GROUP)
-        wp = _pad_to(_pad_to(w_packed, 2, k_pad // 32 * bits), 1, n_pad)
-        kernel = functools.partial(_bmm_t_kernel, bits=bits, bk=bk_)
-        w_spec = pl.BlockSpec((1, bn_, bk_ // 32 * bits),
-                              lambda e_, i, j, k: (e_, j, k))
-    else:
-        # W logical (E, K, N) packed along N; output blocks cut on group
-        # boundaries.
-        assert w_packed.shape[1] == kdim, (w_packed.shape, kdim)
-        n_ceil = w_packed.shape[2] // bits * bitpack.GROUP
-        assert n <= n_ceil
-        bn_, n_pad = _plan_axis(n_ceil, bn, bitpack.GROUP)
-        bk_, k_pad = _plan_axis(kdim, bk, 1)
-        wp = _pad_to(_pad_to(w_packed, 2, n_pad // 32 * bits), 1, k_pad)
-        kernel = functools.partial(_bmm_kernel, bits=bits, bn=bn_)
-        w_spec = pl.BlockSpec((1, bk_, bn_ // 32 * bits),
-                              lambda e_, i, j, k: (e_, k, j))
-
-    bm_, m_pad = _plan_axis(m, bm, 1)
-    x3 = _pad_to(_pad_to(x, 2, k_pad), 1, m_pad)
-    k_steps = k_pad // bk_
-    out = pl.pallas_call(
-        functools.partial(kernel, k_steps=k_steps),
-        grid=(e, m_pad // bm_, n_pad // bn_, k_steps),
-        in_specs=[
-            pl.BlockSpec((1, bm_, bk_), lambda e_, i, j, k: (e_, i, k)),
-            w_spec,
-        ],
-        out_specs=pl.BlockSpec((1, bm_, bn_),
-                               lambda e_, i, j, k: (e_, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, m_pad, n_pad), out_dtype),
-        scratch_shapes=_vmem_scratch(bm_, bn_),
-        interpret=interpret,
-    )(x3, wp)
-
-    return out[:, :m, :n]
+    return _packed_call(x, w_packed, bits, n, transpose, bm, bn, bk,
+                        out_dtype, interpret)

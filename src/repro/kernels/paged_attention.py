@@ -9,16 +9,18 @@ shadow copy the paper's register file avoids. This kernel attends
     SMEM: the per-slot page-id table and valid lengths arrive on the
           scalar-prefetch path (``PrefetchScalarGridSpec``), so the
           BlockSpec index_map can steer each grid step's DMA;
-    HBM:  one physical page of packed words per grid step, fetched
-          straight from the pool row the table names — the dense gather
-          copy never exists;
-    VMEM: static shift/or unpack (``bitpack.unpack_groups``) + Value
-          Converter, then the page's contribution to the online softmax
-          (flash-decoding style m/l/acc scratch, as ``kv_decode``).
+    HBM:  one physical page — ``(page, words)`` packed rows holding every
+          KV head, so the block's minor dims are whole (8, 128) tiles —
+          per grid step, fetched straight from the pool row the table
+          names; the dense gather copy never exists;
+    VMEM: whole-vreg shift/or unpack of each head's lane chunks
+          (``kv_decode.kv_head_rows``) + Value Converter, then the page's
+          contribution to the online softmax (flash-decoding style
+          m/l/acc scratch, shared with ``kv_decode``).
 
 Pages past a sequence's live length all map to the scrap page 0, and
 consecutive grid steps with an unchanged block index skip the re-DMA —
-so HBM traffic per (batch, kv-head) is the pages actually live, not
+so HBM traffic per sequence is the pages actually live, not
 ``max_pages``. Dead-page grid steps also skip the softmax update
 entirely (``pl.when``); the tail of a partially filled page is masked by
 position exactly as the dense kernel masks beyond ``kv_len``.
@@ -39,17 +41,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.compat.pallas import pallas_interpret_default
-from repro.core import bitpack
-from repro.core.formats import FLOAT_FORMATS, decode_float
-
-NEG_INF = -1e30
+from repro.kernels.kv_decode import (
+    NEG_INF,
+    flush_heads,
+    kv_head_rows,
+    online_softmax_step,
+)
 
 
 def _paged_attn_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                        m_ref, l_ref, acc_ref,
-                       *, bits: int, d: int, page: int, max_pages: int):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+                       *, bits: int, hkv: int, page: int, max_pages: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+    d = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -64,102 +68,74 @@ def _paged_attn_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # skipped their DMA.
     @pl.when(j * page < length)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-        if bits:
-            k = decode_float(
-                bitpack.unpack_groups(k_ref[0, :, 0], bits, d),
-                FLOAT_FORMATS[bits])                      # (page, D)
-            v = decode_float(
-                bitpack.unpack_groups(v_ref[0, :, 0], bits, d),
-                FLOAT_FORMATS[bits])
-        else:
-            k = k_ref[0, :, 0].astype(jnp.float32)
-            v = v_ref[0, :, 0].astype(jnp.float32)
-
-        logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        logits = logits * (1.0 / (d ** 0.5))              # (G, page)
-
+        kw, vw = k_ref[0], v_ref[0]                       # (page, W)
         # mask the partially-filled tail page beyond the live length
         pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)
-        logits = jnp.where(pos < length, logits, NEG_INF)
-
-        m_prev = m_ref[...]                               # (G, 1)
-        m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
-        scale = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)                       # (G, page)
-        l_ref[...] = l_ref[...] * scale + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * scale + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+            jnp.int32, (q_ref.shape[2], page), 1)
+        valid = pos < length
+        for h in range(hkv):
+            online_softmax_step(q_ref,
+                                kv_head_rows(kw, h, d, bits, hkv),
+                                kv_head_rows(vw, h, d, bits, hkv),
+                                h, m_ref, l_ref, acc_ref, valid)
 
     @pl.when(j == max_pages - 1)
     def _flush():
-        # kv_len == 0 leaves m == NEG_INF (no page ever accumulated);
-        # emit zeros instead of 0/0 — the same degenerate-row guard as
-        # kv_decode's flush.
-        empty = m_ref[...] <= NEG_INF * 0.5               # (G, 1)
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        out = jnp.where(empty, 0.0, acc_ref[...] / l_safe)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        flush_heads(o_ref, m_ref, l_ref, acc_ref)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bits", "d", "interpret")
+    jax.jit, static_argnames=("bits", "hkv", "interpret")
 )
 def paged_attention(
     q: jnp.ndarray,          # (B, H, D) one new token
-    k_pool: jnp.ndarray,     # (P+1, page, Hkv, W) uint32 packed words,
-    v_pool: jnp.ndarray,     #   or (P+1, page, Hkv, D) dense when bits=0
+    k_pool: jnp.ndarray,     # (P+1, page, packed_group_words(Hkv*D))
+    v_pool: jnp.ndarray,     #   uint32, or (P+1, page, Hkv*D) when bits=0
     table: jnp.ndarray,      # (B, max_pages) int32 physical page ids
     kv_len: jnp.ndarray,     # (B,) valid lengths
     bits: int,
-    d: int,
+    hkv: int,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Attend one token per sequence straight through the page table.
 
-    One grid step owns one (batch, kv-head, table-slot) triple; the
-    slot's physical page id is scalar-prefetched into the DMA index_map,
-    so only the pages the table names ever leave HBM.
+    One grid step owns one (batch, table-slot) pair and every KV head;
+    the slot's physical page id is scalar-prefetched into the DMA
+    index_map, so only the pages the table names ever leave HBM.
     """
     from jax.experimental.pallas import tpu as pltpu
 
     interpret = pallas_interpret_default(interpret)
     b, h, dim = q.shape
-    page, hkv = k_pool.shape[1], k_pool.shape[2]
-    wd = k_pool.shape[3]                  # packed words or dense head_dim
+    page, words = k_pool.shape[1], k_pool.shape[2]
     group = h // hkv
     max_pages = table.shape[1]
 
     qg = q.reshape(b, hkv, group, dim)
     flat_table = table.reshape(-1).astype(jnp.int32)      # (B * mp,)
 
+    def page_index(ib, jp, tab, lens):
+        return (tab[ib * max_pages + jp], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, max_pages),
+        grid=(b, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, group, dim),
-                         lambda ib, ih, jp, tab, lens: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, page, 1, wd),
-                         lambda ib, ih, jp, tab, lens:
-                         (tab[ib * max_pages + jp], 0, ih, 0)),
-            pl.BlockSpec((1, page, 1, wd),
-                         lambda ib, ih, jp, tab, lens:
-                         (tab[ib * max_pages + jp], 0, ih, 0)),
+            pl.BlockSpec((1, hkv, group, dim),
+                         lambda ib, jp, tab, lens: (ib, 0, 0, 0)),
+            pl.BlockSpec((1, page, words), page_index),
+            pl.BlockSpec((1, page, words), page_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, dim),
-                               lambda ib, ih, jp, tab, lens:
-                               (ib, ih, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, group, dim),
+                               lambda ib, jp, tab, lens: (ib, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, dim), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, dim), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, bits=bits, d=dim, page=page,
+        functools.partial(_paged_attn_kernel, bits=bits, hkv=hkv, page=page,
                           max_pages=max_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, dim), q.dtype),

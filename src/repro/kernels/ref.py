@@ -30,9 +30,10 @@ def take_rows_ref(packed: jnp.ndarray, indices: jnp.ndarray, bits: int,
                   n: int, kind: str = "float", signed: bool = True,
                   out_dtype=jnp.float32) -> jnp.ndarray:
     """Gather rows of packed words, decode only the gathered rows — the
-    packed ``embed`` path. packed (R, n*bits/32) uint32, indices (B,) ->
-    (B, n). The Pallas kernel DMAs one row per scalar-prefetched index;
-    this oracle is the same gather in XLA."""
+    packed ``embed`` path. packed (R, packed_group_words(n)) uint32,
+    indices (B,) -> (B, n). The Pallas kernel DMAs the 8-row slab that
+    holds each scalar-prefetched index; this oracle is the same gather in
+    XLA."""
     rows = jnp.take(packed, indices, axis=0)
     codes = bitpack.unpack_groups(rows, bits, n)
     if kind == "float":
@@ -116,19 +117,19 @@ def packed_matmul_dw_ref(x: jnp.ndarray, g: jnp.ndarray,
 
 def kv_decode_ref(
     q: jnp.ndarray,           # (B, H, D)
-    k_packed: jnp.ndarray,    # (B, S, Hkv, D*bits/32) uint32
-    v_packed: jnp.ndarray,    # (B, S, Hkv, D*bits/32) uint32
+    k_packed: jnp.ndarray,    # (B, S, packed_group_words(Hkv*D)) uint32
+    v_packed: jnp.ndarray,    # (B, S, packed_group_words(Hkv*D)) uint32
     bits: int,
-    d: int,
+    hkv: int,
     kv_len: jnp.ndarray | None = None,   # (B,) valid lengths, else full S
 ) -> jnp.ndarray:
-    """Single-token attention decode over a packed KV cache."""
+    """Single-token attention decode over a packed KV cache (one packed
+    row per token holding every KV head)."""
     b, h, dim = q.shape
     s = k_packed.shape[1]
-    hkv = k_packed.shape[2]
     group = h // hkv
-    k = unpack_ref(k_packed, bits, d)                   # (B, S, Hkv, D)
-    v = unpack_ref(v_packed, bits, d)
+    k = unpack_ref(k_packed, bits, hkv * dim).reshape(b, s, hkv, dim)
+    v = unpack_ref(v_packed, bits, hkv * dim).reshape(b, s, hkv, dim)
     qg = q.reshape(b, hkv, group, dim).astype(jnp.float32)
     logits = jnp.einsum("bkgd,bskd->bkgs", qg, k) / jnp.sqrt(float(dim))
     if kv_len is not None:
@@ -147,12 +148,12 @@ def kv_decode_ref(
 
 def paged_attention_ref(
     q: jnp.ndarray,          # (B, H, D) one new token
-    k_pool: jnp.ndarray,     # (P+1, page, Hkv, W) uint32 packed words,
-    v_pool: jnp.ndarray,     #   or (P+1, page, Hkv, D) dense when bits=0
+    k_pool: jnp.ndarray,     # (P+1, page, packed_group_words(Hkv*D))
+    v_pool: jnp.ndarray,     #   uint32, or (P+1, page, Hkv*D) when bits=0
     table: jnp.ndarray,      # (B, max_pages) int32 physical page ids
     kv_len: jnp.ndarray,     # (B,) valid lengths
     bits: int,
-    d: int,
+    hkv: int,
 ) -> jnp.ndarray:
     """Fused paged-attention oracle: gather the pages the table names
     into the dense per-sequence view, then run the dense kernels' exact
@@ -164,17 +165,30 @@ def paged_attention_ref(
     weight exactly, so scrap garbage never leaks into the output."""
 
     def gather(pool):
-        g = jnp.take(pool, table, axis=0)     # (B, mp, page, Hkv, wd)
+        g = jnp.take(pool, table, axis=0)     # (B, mp, page, wd)
         b_, mp, pg = g.shape[0], g.shape[1], g.shape[2]
         return g.reshape((b_, mp * pg) + g.shape[3:])
 
     kc, vc = gather(k_pool), gather(v_pool)
     if bits:
-        return kv_decode_ref(q, kc, vc, bits, d, kv_len)
-    # dense width: the exact models.attention.decode_attention program
+        return kv_decode_ref(q, kc, vc, bits, hkv, kv_len)
+    return dense_decode_ref(q, kc, vc, kv_len, hkv)
+
+
+def dense_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
+                     v_cache: jnp.ndarray, kv_len: jnp.ndarray,
+                     hkv: int) -> jnp.ndarray:
+    """One-token attention over a dense-dtype cache (B, S, Hkv*D): the
+    exact ``models.attention.decode_attention`` program for unpacked KV.
+    kv_len == 0 rows (dead slots; live slots always append before they
+    attend) emit zeros like the kernel's flush guard, instead of the
+    garbage-mean a fully NEG_INF-masked softmax produces. For live rows
+    the select passes the identical value through bit-for-bit."""
     b, h, dim = q.shape
-    s, hkv = kc.shape[1], kc.shape[2]
+    s = k_cache.shape[1]
     group = h // hkv
+    kc = k_cache.reshape(b, s, hkv, dim)
+    vc = v_cache.reshape(b, s, hkv, dim)
     qg = q.reshape(b, hkv, group, dim).astype(jnp.float32)
     logits = jnp.einsum(
         "bkgd,bskd->bkgs", qg, kc.astype(jnp.float32)
@@ -183,10 +197,6 @@ def paged_attention_ref(
     logits = jnp.where(mask, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, vc.astype(jnp.float32))
-    # kv_len == 0 rows (dead slots; live slots always append before they
-    # attend) emit zeros like the kernel's flush guard, instead of the
-    # garbage-mean a fully NEG_INF-masked softmax produces. For live rows
-    # the select passes the identical value through bit-for-bit.
     out = jnp.where((kv_len == 0)[:, None, None], 0.0,
                     out.reshape(b, h, dim))
     return out.astype(q.dtype)

@@ -1,14 +1,3 @@
-import os
-# Append rather than assign: CPU CI drives this module under its own
-# --xla_force_host_platform_device_count (the mesh matrix below) which
-# must win, while unrelated user flags (--xla_dump_to=...) must not
-# silently drop the 512-chip production default.
-_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=512").strip()
-del _flags
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: for each
@@ -40,6 +29,7 @@ Results are appended as JSON lines under benchmarks/results/dryrun/.
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import List, Optional, Sequence, Tuple
@@ -246,7 +236,20 @@ def run_mesh_matrix(arch: str = "qwen3_8b") -> List[dict]:
     return records
 
 
+def _default_host_devices() -> None:
+    """512 forced host devices unless the caller chose a count. Appended
+    rather than assigned: CPU CI drives the mesh matrix under its own
+    --xla_force_host_platform_device_count, which must win, while
+    unrelated user flags (--xla_dump_to=...) must not silently drop the
+    512-chip production default. Runs before the first backend use."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_force_host_platform_device_count=512").strip()
+
+
 def main() -> None:
+    _default_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="one arch id (default all)")
     ap.add_argument("--shape", default=None, help="one shape (default all)")

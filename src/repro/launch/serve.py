@@ -42,7 +42,10 @@ def main() -> None:
     ap.add_argument("--max-seq-len", type=int, default=64)
     ap.add_argument("--kv-bits", type=int, default=None)
     ap.add_argument("--slots", type=int, default=None)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's tiny same-family variant "
+                         "(CPU smoke runs); default is the published "
+                         "widths")
     ap.add_argument("--speculative", type=int, default=0, metavar="K",
                     help="draft K tokens/tick through the narrow plan; "
                          "0 = plain engine")
@@ -96,11 +99,12 @@ def main() -> None:
                          "(0: only the final one at drain)")
     args = ap.parse_args()
 
-    from repro import obs
+    from repro import compat, obs
     from repro.configs import get_config
     from repro.core.compress import CompressionPlan
     from repro.serving import ServeEngine, SpeculativeEngine
 
+    compat.enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -51,7 +51,7 @@ def _tree_shardings(tree, mesh: Mesh, mode: str):
     from repro.core.tensor_store import is_packed
 
     def leaf_spec(path, leaf):
-        # packed leaves shard by their logical spec with the group-of-32
+        # packed leaves shard by their logical spec with the lane-tiled
         # word axis kept intact (distributed.sharding.spec_for_packed)
         return shard_leaf(path_str(path), leaf, mesh, mode)
     return tree_map_with_path(leaf_spec, tree, is_leaf=is_packed)
@@ -82,9 +82,9 @@ def _state_shardings(state, mesh: Mesh) -> Any:
             def ns(axes):
                 return NamedSharding(
                     mesh, drop_indivisible(resolve_axes(axes), leaf.shape))
-            if "k" in keys or "v" in keys:          # (L,B,S,H,D/W)
+            if "k" in keys or "v" in keys:          # (L,B,S,W)
                 return ns((None, "data", "model") + (None,) * (nd - 3))
-            if "ck" in keys or "cv" in keys:        # (L,B,Se,H,D)
+            if "ck" in keys or "cv" in keys:        # (L,B,Se,W)
                 return ns((None, "data") + (None,) * (nd - 2))
             if "ssm" in keys:                       # (L,B,di,N)
                 return ns((None, "data", "model", None))

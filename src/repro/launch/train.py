@@ -53,9 +53,11 @@ def main() -> None:
         import jax
         jax.distributed.initialize()       # multi-host pod entry
 
+    from repro import compat
     from repro.configs import get_config
     from repro.train import Trainer, TrainConfig
 
+    compat.enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

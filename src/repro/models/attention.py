@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tensor_store import PackedTensor, is_packed, pack_tensor
+from repro.core import bitpack
+from repro.core.tensor_store import is_packed
 from repro.distributed.sharding import constrain
 from repro.kernels import ops as kops
 
@@ -121,12 +122,23 @@ def flash_attention(
     return out.astype(q.dtype)
 
 
+def kv_row_width(n_kv_heads: int, head_dim: int,
+                 kv_bits: Optional[int] = None) -> int:
+    """Minor dim of a KV cache row: one token's K (or V) for every KV
+    head — packed words along the flattened (Hkv * D) axis when
+    ``kv_bits`` packs, else Hkv * D dense values. One packed row per
+    token keeps the cache's minor dim whole 128-lane tiles at real widths
+    (a per-head row of 64 words would half-fill every tile)."""
+    n = n_kv_heads * head_dim
+    return bitpack.packed_group_words(n, kv_bits) if kv_bits else n
+
+
 def decode_attention(
     q: jnp.ndarray,                        # (B, H, D) one new token
-    k_cache, v_cache,                      # (B, S, Hkv, D) float, or
-                                           # (B, S, Hkv, D*bits/32) uint32
+    k_cache, v_cache,                      # (B, S, kv_row_width) rows
     kv_len: jnp.ndarray,                   # (B,) valid lengths
-    kv_bits: Optional[int] = None,
+    kv_bits: Optional[int],
+    hkv: int,                              # KV heads per row
 ) -> jnp.ndarray:
     """Score one token against the cache (packed path = kernel dispatch).
 
@@ -138,29 +150,30 @@ def decode_attention(
             k_cache.bits, k_cache.data, v_cache.data
         )
     if kv_bits:
-        return kops.kv_decode(
-            q, k_cache, v_cache, kv_len, kv_bits, q.shape[-1]
-        )
+        return kops.kv_decode(q, k_cache, v_cache, kv_len, kv_bits, hkv)
     b, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    s = k_cache.shape[1]
     g = h // hkv
     qg = q.reshape(b, hkv, g, d).astype(jnp.float32)
     logits = jnp.einsum(
-        "bkgd,bskd->bkgs", qg, k_cache.astype(jnp.float32)
+        "bkgd,bskd->bkgs", qg,
+        k_cache.reshape(b, s, hkv, d).astype(jnp.float32)
     ) / np.sqrt(d)
     mask = jnp.arange(s)[None, None, None, :] < kv_len[:, None, None, None]
     logits = jnp.where(mask, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bskd->bkgd", p,
+                     v_cache.reshape(b, s, hkv, d).astype(jnp.float32))
     return out.reshape(b, h, d).astype(q.dtype)
 
 
 def paged_decode_attention(
     q: jnp.ndarray,                        # (B, H, D) one new token
-    k_pool, v_pool,                        # (P+1, page, Hkv, W|D) pools
+    k_pool, v_pool,                        # (P+1, page, kv_row_width) pools
     table: jnp.ndarray,                    # (B, max_pages) int32 page ids
     kv_len: jnp.ndarray,                   # (B,) valid lengths
-    kv_bits: Optional[int] = None,
+    kv_bits: Optional[int],
+    hkv: int,
     fallback: bool = False,
 ) -> jnp.ndarray:
     """Score one token straight through the page table — the fused paged
@@ -169,35 +182,33 @@ def paged_decode_attention(
     ``fallback=True`` demotes to the gather-materialize oracle (recorded
     as such for the dispatch linter)."""
     return kops.paged_attention(q, k_pool, v_pool, table, kv_len,
-                                kv_bits or 0, q.shape[-1],
-                                fallback=fallback)
+                                kv_bits or 0, hkv, fallback=fallback)
+
+
+def _kv_rows(x_new, kv_bits):
+    """One token's (B, Hkv, D) K or V -> its (B, kv_row_width) cache row
+    (``kops.pack`` over the flattened heads when the cache packs)."""
+    row = x_new.reshape(x_new.shape[0], -1)
+    return kops.pack(row.astype(jnp.float32), kv_bits) if kv_bits else row
 
 
 def append_kv_pool_row(k_pool, v_pool, k_new, v_new, table, kv_len,
                        kv_bits: Optional[int] = None):
-    """Persist one token's (Hkv, D) K/V row straight to its physical
-    page — the fused paged path's append. The row packs exactly as
-    ``update_kv_cache`` packs it (same ``kops.pack`` call on the same
-    reshaped operand), so the pool holds bit-identical words whether the
-    row arrived here or through the gather-view + ``scatter_kv_row``
-    round-trip. Out-of-range lengths (dead slots) clamp onto the scrap
-    page, mirroring ``scatter_kv_row``."""
-    if kv_bits:
-        b = k_new.shape[0]
-        k_row = kops.pack(
-            k_new.reshape(b, -1).astype(jnp.float32), kv_bits
-        ).reshape(b, k_new.shape[1], -1)
-        v_row = kops.pack(
-            v_new.reshape(b, -1).astype(jnp.float32), kv_bits
-        ).reshape(b, v_new.shape[1], -1)
-    else:
-        k_row, v_row = k_new, v_new
-    return (_scatter_pool_row(k_pool, k_row, table, kv_len),
-            _scatter_pool_row(v_pool, v_row, table, kv_len))
+    """Persist one token's K/V row straight to its physical page — the
+    fused paged path's append. The row packs exactly as
+    ``update_kv_cache`` packs it (same ``_kv_rows``), so the pool holds
+    bit-identical words whether the row arrived here or through the
+    gather-view + ``scatter_kv_row`` round-trip. Out-of-range lengths
+    (dead slots) clamp onto the scrap page, mirroring
+    ``scatter_kv_row``."""
+    return (_scatter_pool_row(k_pool, _kv_rows(k_new, kv_bits), table,
+                              kv_len),
+            _scatter_pool_row(v_pool, _kv_rows(v_new, kv_bits), table,
+                              kv_len))
 
 
 def _scatter_pool_row(pool, row, table, kv_len):
-    """Write each sequence's (Hkv, W) row at pool position
+    """Write each sequence's row at pool position
     (table[b, len // page], len % page)."""
     page = pool.shape[1]
     mp = table.shape[1]
@@ -214,24 +225,13 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, kv_len,
                     kv_bits: Optional[int] = None):
     """Insert one token's K/V at position kv_len per sequence.
 
-    Packed caches (uint32 words) update word-aligned lanes: one token's
-    (Hkv, D) row packs to (Hkv, D*bits/32) words — a masked writeback of
-    whole words, so no read-modify-write of neighbours (Section 3.2.6
+    Packed caches (uint32 words) update whole rows: one token's
+    (Hkv, D) K packs to one row of words — a masked writeback of whole
+    words, so no read-modify-write of neighbours (Section 3.2.6
     analogue).
     """
-    if kv_bits:
-        b = k_new.shape[0]
-        k_words = kops.pack(
-            k_new.reshape(b, -1).astype(jnp.float32), kv_bits
-        ).reshape(b, 1, k_new.shape[1], -1)
-        v_words = kops.pack(
-            v_new.reshape(b, -1).astype(jnp.float32), kv_bits
-        ).reshape(b, 1, v_new.shape[1], -1)
-        kd = _dus_rows(k_cache, k_words, kv_len)
-        vd = _dus_rows(v_cache, v_words, kv_len)
-        return kd, vd
-    k_cache = _dus_rows(k_cache, k_new[:, None], kv_len)
-    v_cache = _dus_rows(v_cache, v_new[:, None], kv_len)
+    k_cache = _dus_rows(k_cache, _kv_rows(k_new, kv_bits)[:, None], kv_len)
+    v_cache = _dus_rows(v_cache, _kv_rows(v_new, kv_bits)[:, None], kv_len)
     return k_cache, v_cache
 
 

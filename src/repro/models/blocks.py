@@ -114,7 +114,7 @@ def attention_decode(
                else cfg.compression.kv_bits)
     if cross:
         o = decode_attention(
-            q[:, 0], state["ck"], state["cv"], state["clen"], kv_bits
+            q[:, 0], state["ck"], state["cv"], state["clen"], kv_bits, hkv
         )
         return x + L.linear(o.reshape(b, 1, h * hd), p["wo"],
                             "...f,fd->...d"), state
@@ -130,7 +130,7 @@ def attention_decode(
     eff_len = state["len"] + 1
     if window:
         eff_len = jnp.minimum(eff_len, window)
-    o = decode_attention(q[:, 0], kc, vc, eff_len, kv_bits)
+    o = decode_attention(q[:, 0], kc, vc, eff_len, kv_bits, hkv)
     state = dict(state, k=kc, v=vc)
     return x + L.linear(o.reshape(b, 1, h * hd), p["wo"],
                         "...f,fd->...d"), state
@@ -148,7 +148,7 @@ def attention_decode_paged(
     to its physical page (``append_kv_pool_row``) and attention walks the
     pool through the table (``kernels.paged_attention``) — the dense
     gathered view never materializes. ``kv`` is one layer's pool slice
-    ``{"k", "v"}`` of shape (P+1, page, Hkv, W). ``oracle=True`` routes
+    ``{"k", "v"}`` of shape (P+1, page, kv_row_width). ``oracle=True`` routes
     the attention through the gather-materialize reference instead (the
     linter-visible parity escape hatch)."""
     b, _, d = x.shape
@@ -168,7 +168,7 @@ def attention_decode_paged(
     kc, vc = append_kv_pool_row(kv["k"], kv["v"], k[:, 0], v[:, 0],
                                 table, kv_len, kv_bits)
     o = paged_decode_attention(q[:, 0], kc, vc, table, kv_len + 1,
-                               kv_bits, fallback=oracle)
+                               kv_bits, hkv, fallback=oracle)
     return x + L.linear(o.reshape(b, 1, h * hd), p["wo"],
                         "...f,fd->...d"), {"k": kc, "v": vc}
 

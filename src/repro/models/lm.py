@@ -37,11 +37,11 @@ import numpy as np
 
 from repro import compat
 
-from repro.core import bitpack
 from repro.distributed.sharding import constrain
 from repro.kernels import ops as kops
 from repro.models import blocks as B
 from repro.models import layers as L
+from repro.models.attention import kv_row_width
 from repro.models.config import ModelConfig, ShapeConfig
 
 
@@ -49,8 +49,29 @@ def _stack_init(init_fn, rng, n, *args):
     return jax.vmap(lambda r: init_fn(r, *args))(jax.random.split(rng, n))
 
 
-def _packed_kv_words(d: int, bits: int) -> int:
-    return bitpack.packed_group_words(d, bits)
+def _layer_init(fn, cfg, key):
+    return fn(key, cfg)
+
+
+@dataclasses.dataclass(frozen=True)
+class InitPart:
+    """One top-level entry of the parameter tree, not yet evaluated:
+    ``fn(key)`` builds it — one layer of a stack when ``stacked`` (the
+    stack is ``fn`` over the (n, 2) ``keys``, which is exactly what
+    ``_stack_init``'s vmap computes), the whole entry otherwise."""
+
+    fn: Any
+    keys: Any
+    stacked: bool = False
+
+    def build(self):
+        if self.stacked:
+            return jax.vmap(self.fn)(self.keys)
+        return self.fn(self.keys)
+
+
+def _is_part(x) -> bool:
+    return isinstance(x, InitPart)
 
 
 # ---------------------------------------------------------------------------
@@ -60,9 +81,9 @@ def _packed_kv_words(d: int, bits: int) -> int:
 def gather_kv_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Materialize a per-sequence cache view from the page pool.
 
-    pool: (P, page, Hkv, W) one layer's physical pages (page 0 = scrap);
+    pool: (P, page, W) one layer's physical pages (page 0 = scrap);
     table: (B, max_pages) int32 physical page ids (0 where unallocated).
-    Returns (B, max_pages*page, Hkv, W) — logical row ``p`` of sequence
+    Returns (B, max_pages*page, W) — logical row ``p`` of sequence
     ``b`` is pool row (table[b, p // page], p % page). Rows gathered
     through unallocated (scrap) entries are garbage, but they only ever
     sit at positions >= the sequence's valid length, where attention
@@ -76,7 +97,7 @@ def gather_kv_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     kops.record_dispatch("gather_kv_pages", "materialized",
                          pool.size * pool.dtype.itemsize,
                          shape=pool.shape)
-    g = jnp.take(pool, table, axis=0)          # (B, mp, page, Hkv, W)
+    g = jnp.take(pool, table, axis=0)          # (B, mp, page, W)
     b, mp, pg = g.shape[0], g.shape[1], g.shape[2]
     return g.reshape((b, mp * pg) + g.shape[3:])
 
@@ -85,7 +106,7 @@ def scatter_kv_row(pool: jnp.ndarray, view: jnp.ndarray,
                    table: jnp.ndarray, lens: jnp.ndarray) -> jnp.ndarray:
     """Persist the row appended at position ``lens[b]`` back to the pool.
 
-    ``view`` is the gathered (B, S, Hkv, W) cache *after* the append path
+    ``view`` is the gathered (B, S, W) cache *after* the append path
     wrote one token's row at each sequence's length; everything below
     ``lens`` is already pool-resident, so only that single row needs to
     reach the physical page. Out-of-range lengths (a free slot whose
@@ -97,7 +118,7 @@ def scatter_kv_row(pool: jnp.ndarray, view: jnp.ndarray,
     pos = jnp.minimum(lens, view.shape[1] - 1)
     row = jax.vmap(
         lambda v, p: jax.lax.dynamic_slice_in_dim(v, p, 1, 0)[0]
-    )(view, pos)                                # (B, Hkv, W)
+    )(view, pos)                                # (B, W)
     pidx = jnp.minimum(pos // page, mp - 1)
     ids = jnp.take_along_axis(table, pidx[:, None], axis=1)[:, 0]
     phys = ids * page + pos % page
@@ -117,70 +138,129 @@ class LM:
     paged_attn: bool = True
 
     # ------------------------------------------------------------------ init
-    def init(self, rng) -> Dict:
+    def init_parts(self, rng) -> Dict:
+        """The parameter tree with every top-level entry left unevaluated
+        as an :class:`InitPart` — each layer stack as its one-layer init
+        over the stack's keys. :meth:`init` builds them all at once;
+        :meth:`init_packed` builds (and packs) one leaf at a time."""
         cfg = self.cfg
         dt = cfg.dtype
         r = jax.random.split(rng, 8)
-        params: Dict[str, Any] = {
-            "embed": L.init_dense(r[0], (cfg.vocab_size, cfg.d_model),
-                                  scale=0.02, dtype=dt),
-            "final_norm": jnp.zeros((cfg.d_model,), dt),
+
+        def stack(fn, key, n):
+            return InitPart(functools.partial(_layer_init, fn, cfg),
+                            jax.random.split(key, n), stacked=True)
+
+        def zeros(shape):
+            return InitPart(lambda _k: jnp.zeros(shape, dt), r[7])
+
+        parts: Dict[str, Any] = {
+            "embed": InitPart(functools.partial(
+                L.init_dense, shape=(cfg.vocab_size, cfg.d_model),
+                scale=0.02, dtype=dt), r[0]),
+            "final_norm": zeros((cfg.d_model,)),
         }
         if not cfg.tie_embeddings:
-            params["lm_head"] = L.init_dense(
-                r[1], (cfg.d_model, cfg.vocab_size), dtype=dt
-            )
+            parts["lm_head"] = InitPart(functools.partial(
+                L.init_dense, shape=(cfg.d_model, cfg.vocab_size),
+                dtype=dt), r[1])
         fam = cfg.family
         if fam in ("dense", "vlm"):
-            params["blocks"] = {
-                "attn": _stack_init(B.init_attention, r[2], cfg.n_layers, cfg),
-                "mlp": _stack_init(B.init_mlp, r[3], cfg.n_layers, cfg),
+            parts["blocks"] = {
+                "attn": stack(B.init_attention, r[2], cfg.n_layers),
+                "mlp": stack(B.init_mlp, r[3], cfg.n_layers),
             }
         elif fam == "moe":
-            params["blocks"] = {
-                "attn": _stack_init(B.init_attention, r[2], cfg.n_layers, cfg),
-                "moe": _stack_init(B.init_moe, r[3], cfg.n_layers, cfg),
+            parts["blocks"] = {
+                "attn": stack(B.init_attention, r[2], cfg.n_layers),
+                "moe": stack(B.init_moe, r[3], cfg.n_layers),
             }
         elif fam == "ssm":
-            params["blocks"] = {
-                "mamba": _stack_init(B.init_mamba, r[2], cfg.n_layers, cfg),
+            parts["blocks"] = {
+                "mamba": stack(B.init_mamba, r[2], cfg.n_layers),
             }
         elif fam == "hybrid":
             g = cfg.pattern_rec + cfg.pattern_attn
             groups = cfg.n_layers // g
             tail = cfg.n_layers - groups * g
-            params["blocks"] = {
-                "rec": _stack_init(
+            parts["blocks"] = {
+                "rec": stack(
                     lambda rr, c: _stack_init(B.init_rglru, rr,
                                               cfg.pattern_rec, c),
-                    r[2], groups, cfg),
-                "attn": _stack_init(B.init_attention, r[3], groups, cfg),
-                "mlp": _stack_init(
+                    r[2], groups),
+                "attn": stack(B.init_attention, r[3], groups),
+                "mlp": stack(
                     lambda rr, c: _stack_init(B.init_mlp, rr, g, c),
-                    r[4], groups, cfg),
+                    r[4], groups),
             }
             if tail:
-                params["tail"] = {
-                    "rec": _stack_init(B.init_rglru, r[5], tail, cfg),
-                    "mlp": _stack_init(B.init_mlp, r[6], tail, cfg),
+                parts["tail"] = {
+                    "rec": stack(B.init_rglru, r[5], tail),
+                    "mlp": stack(B.init_mlp, r[6], tail),
                 }
         elif fam == "encdec":
-            params["enc_blocks"] = {
-                "attn": _stack_init(B.init_attention, r[2],
-                                    cfg.encoder_layers, cfg),
-                "mlp": _stack_init(B.init_mlp, r[3], cfg.encoder_layers, cfg),
+            parts["enc_blocks"] = {
+                "attn": stack(B.init_attention, r[2], cfg.encoder_layers),
+                "mlp": stack(B.init_mlp, r[3], cfg.encoder_layers),
             }
-            params["blocks"] = {
-                "self": _stack_init(B.init_attention, r[4], cfg.n_layers,
-                                    cfg),
-                "cross": _stack_init(B.init_attention, r[5], cfg.n_layers,
-                                     cfg),
-                "mlp": _stack_init(B.init_mlp, r[6], cfg.n_layers, cfg),
+            parts["blocks"] = {
+                "self": stack(B.init_attention, r[4], cfg.n_layers),
+                "cross": stack(B.init_attention, r[5], cfg.n_layers),
+                "mlp": stack(B.init_mlp, r[6], cfg.n_layers),
             }
-            params["enc_norm"] = jnp.zeros((cfg.d_model,), dt)
+            parts["enc_norm"] = zeros((cfg.d_model,))
         else:
             raise ValueError(fam)
-        return params
+        return parts
+
+    def init(self, rng) -> Dict:
+        return compat.tree_map(lambda part: part.build(),
+                               self.init_parts(rng), is_leaf=_is_part)
+
+    def init_packed(self, rng, plan=None) -> Dict:
+        """:meth:`init`'s exact values, built leaf by leaf and packed at
+        ``plan``'s widths on the way (``None``: nothing packs).
+
+        Each leaf is initialised (and packed) inside one jitted call; a
+        layer-stacked leaf loops over its layers (``lax.map``), so the
+        largest unpacked intermediate is one layer of one leaf. Neither
+        the whole unpacked tree nor an f32 copy of a stacked leaf ever
+        exists — what lets a model whose dense tree exceeds device
+        memory come up packed."""
+        from repro.core.tensor_store import pack_tensor
+
+        parts = self.init_parts(rng)
+        shapes = jax.eval_shape(self.init, rng)
+        flat, treedef = compat.tree_flatten_with_path(shapes)
+        jobs = []
+        for path, sds in flat:
+            keys = [p.key for p in path]
+            part, depth = parts, 0
+            while not _is_part(part):
+                part, depth = part[keys[depth]], depth + 1
+            spec = plan.bits_of(path, sds) if plan is not None else None
+            bits, signed = spec if isinstance(spec, tuple) else (spec, True)
+            jobs.append((part, tuple(keys[depth:]), bits, signed))
+
+        def build(part, sub, bits, signed):
+            def one(key):
+                leaf = part.fn(key)
+                for k in sub:
+                    leaf = leaf[k]
+                if bits is None or bits >= 32:
+                    return leaf
+                return pack_tensor(leaf, bits, signed=signed)
+            if part.stacked:
+                return jax.jit(lambda ks: jax.lax.map(one, ks))(part.keys)
+            return jax.jit(one)(part.keys)
+
+        # whole-tensor leaves (embedding, head) first: their one-shot init
+        # has the largest transient, best paid while the device is empty
+        order = sorted(range(len(jobs)), key=lambda i: jobs[i][0].stacked)
+        leaves = [None] * len(jobs)
+        for i in order:
+            leaves[i] = build(*jobs[i])
+        return compat.tree_unflatten(treedef, leaves)
 
     # -------------------------------------------------------------- forward
     def _positions(self, batch_shape, s):
@@ -402,16 +482,9 @@ class LM:
               else (lambda sh, d: jnp.zeros(sh, d)))
 
         def kv(layers, s, bits=kv_bits):
-            if bits:
-                w = _packed_kv_words(hd, bits)
-                return {
-                    "k": mk((layers, batch_size, s, hkv, w), jnp.uint32),
-                    "v": mk((layers, batch_size, s, hkv, w), jnp.uint32),
-                }
-            return {
-                "k": mk((layers, batch_size, s, hkv, hd), dt),
-                "v": mk((layers, batch_size, s, hkv, hd), dt),
-            }
+            shape = (layers, batch_size, s, kv_row_width(hkv, hd, bits))
+            dtype = jnp.uint32 if bits else dt
+            return {"k": mk(shape, dtype), "v": mk(shape, dtype)}
 
         segs = self._kv_segment_layout()
         state: Dict[str, Any] = {
@@ -451,21 +524,10 @@ class LM:
         elif fam == "encdec":
             state["kv"] = kv(cfg.n_layers, seq_len)
             # cross K/V computed from the encoder at prefill time
-            if kv_bits:
-                w = _packed_kv_words(hd, kv_bits)
-                state["cross"] = {
-                    "ck": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, w), jnp.uint32),
-                    "cv": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, w), jnp.uint32),
-                }
-            else:
-                state["cross"] = {
-                    "ck": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, hd), dt),
-                    "cv": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, hd), dt),
-                }
+            shape = (cfg.n_layers, batch_size, cfg.encoder_seq,
+                     kv_row_width(hkv, hd, kv_bits))
+            dtype = jnp.uint32 if kv_bits else dt
+            state["cross"] = {"ck": mk(shape, dtype), "cv": mk(shape, dtype)}
             state["clen"] = mk((batch_size,), jnp.int32)
         return state
 
@@ -476,8 +538,8 @@ class LM:
         pool of ``n_pages`` physical pages (plus the scrap page 0) shared
         by all sequences, plus a per-sequence page table. Every page
         holds ``page_size`` whole rows, each packed exactly as the dense
-        cache packs them (the group-of-32 word layout along head_dim), so
-        any gathered run of pages stays fused-decodable by
+        cache packs them (one lane-tiled row of every KV head per token),
+        so any gathered run of pages stays fused-decodable by
         ``kernels.kv_decode``. ``page_size`` must divide ``seq_len`` so
         the gathered view has the dense cache's exact shape — which is
         what makes paged decode bitwise identical to dense decode.
@@ -503,17 +565,10 @@ class LM:
               else (lambda sh, d: jnp.zeros(sh, d)))
 
         def kv_pool(layers, bits=kv_bits):
-            p1 = n_pages + 1                      # + scrap page 0
-            if bits:
-                w = _packed_kv_words(hd, bits)
-                return {
-                    "k": mk((layers, p1, page_size, hkv, w), jnp.uint32),
-                    "v": mk((layers, p1, page_size, hkv, w), jnp.uint32),
-                }
-            return {
-                "k": mk((layers, p1, page_size, hkv, hd), dt),
-                "v": mk((layers, p1, page_size, hkv, hd), dt),
-            }
+            shape = (layers, n_pages + 1,         # + scrap page 0
+                     page_size, kv_row_width(hkv, hd, bits))
+            dtype = jnp.uint32 if bits else dt
+            return {"k": mk(shape, dtype), "v": mk(shape, dtype)}
 
         segs = self._kv_segment_layout()
         state: Dict[str, Any] = {
@@ -525,21 +580,10 @@ class LM:
         if cfg.family == "encdec":
             # the cross cache is prompt-scoped and fixed-length — per-slot
             # dense regions are already exactly sized, so it stays dense
-            if kv_bits:
-                w = _packed_kv_words(hd, kv_bits)
-                state["cross"] = {
-                    "ck": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, w), jnp.uint32),
-                    "cv": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, w), jnp.uint32),
-                }
-            else:
-                state["cross"] = {
-                    "ck": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, hd), dt),
-                    "cv": mk((cfg.n_layers, batch_size, cfg.encoder_seq,
-                              hkv, hd), dt),
-                }
+            shape = (cfg.n_layers, batch_size, cfg.encoder_seq,
+                     kv_row_width(hkv, hd, kv_bits))
+            dtype = jnp.uint32 if kv_bits else dt
+            state["cross"] = {"ck": mk(shape, dtype), "cv": mk(shape, dtype)}
             state["clen"] = mk((batch_size,), jnp.int32)
         return state
 

@@ -12,7 +12,7 @@ Byte-accounting invariant (the paper's saving as a live counter): a
 snapshot's cumulative ``weight_read_bytes_fused`` must equal
 ``weight_passes x fused_analytic_bytes_per_pass`` — where the analytic
 per-pass figure is the bits/32 model summed per packed leaf — within
-``BYTE_TOLERANCE`` (group-of-32 padding is the only slack).
+``BYTE_TOLERANCE`` (lane-tile padding is the only slack).
 ``validate_metrics_jsonl`` enforces it on the final snapshot of a
 stream, for the target and (when speculative) the draft.
 """

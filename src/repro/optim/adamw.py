@@ -10,7 +10,7 @@ re-truncated (Value Truncator path) — with an optional error-feedback
 residual so truncation noise doesn't bias the moment EMA.
 
 All of it is jnp, so the whole update jits and shards; packed payloads
-shard exactly like their logical tensors (group-of-32 layout).
+shard exactly like their logical tensors (lane-tiled layout).
 """
 from __future__ import annotations
 
@@ -58,7 +58,7 @@ def _pack_moment(x: jnp.ndarray, bits: Optional[int]):
     """f32 moment -> packed uint32 payload.
 
     Packs along the *last* axis, preserving rank, so the payload inherits
-    the parameter's PartitionSpec verbatim (group-of-32 words scale the
+    the parameter's PartitionSpec verbatim (lane-tiled words scale the
     last dim by bits/32) — no resharding collectives appear around the
     optimizer. Scalars/vectors stay f32 (packing overhead > payload)."""
     if not bits or bits >= 32 or x.ndim < 2:

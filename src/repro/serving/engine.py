@@ -24,7 +24,8 @@ batch -> each weight read amortized over more tokens -> tokens/s up.
     tokens per call.
 
 ``pack_weights=True`` packs every matmul-eligible weight at the config's
-planned width (``core.compress.uniform_plan`` + ``repack``), putting the
+planned width (``core.compress.uniform_plan`` over the parameter shapes,
+packed leaf by leaf in ``LM.init_packed``), putting the
 fused packed-matmul and packed-embed-gather paths on the serving hot
 path. Sequences must fit ``max_seq_len`` (prompt + new tokens); the
 engine does not evict mid-sequence.
@@ -45,8 +46,12 @@ import numpy as np
 from repro import obs
 from repro.compat import jit, prng_fold_in, prng_key
 from repro.core.allocator import KVPagePool, PoolExhausted
-from repro.core.compress import repack, uniform_plan
-from repro.core.occupancy import TPU_V5E, TPUChipConfig, decode_residency
+from repro.core.compress import uniform_plan
+from repro.core.occupancy import (
+    TPUChipConfig,
+    chip_for_device,
+    decode_residency,
+)
 from repro.core.tensor_store import tree_bytes, weight_pass_bytes
 from repro.models.config import ModelConfig
 from repro.models.lm import LM
@@ -106,7 +111,7 @@ class ServeEngine:
     cfg: ModelConfig
     max_seq_len: int = 256
     max_slots: Optional[int] = None
-    chip: TPUChipConfig = TPU_V5E
+    chip: Optional[TPUChipConfig] = None   # None: this process's device
     greedy: bool = True
     bos_token: int = 0             # fed when a request has no prompt
     max_results: int = 65536       # finished-output retention (FIFO)
@@ -162,12 +167,18 @@ class ServeEngine:
                     comp, kv_bits=max(widths), kv_layer_bits=widths)
             self.cfg = dataclasses.replace(self.cfg, compression=comp)
         self.lm = LM(self.cfg, paged_attn=self.paged_attn)
-        self.params = self.lm.init(prng_key(0))
+        if self.chip is None:
+            self.chip = chip_for_device(jax.devices()[0])
+        # the plan comes from the parameter *shapes*, and every leaf is
+        # initialised and packed in its own jitted call: the dense tree
+        # of a full-width model never exists, only its packed form
+        key = prng_key(0)
         self.weight_plan = None
         if self.pack_weights or self.plan is not None:
             self.weight_plan = self.plan or uniform_plan(
-                self.params, self.cfg.resolved_weight_bits)
-            self.params = repack(self.params, self.weight_plan)
+                jax.eval_shape(self.lm.init, key),
+                self.cfg.resolved_weight_bits)
+        self.params = self.lm.init_packed(key, self.weight_plan)
         # per-pass byte figures, fixed at init: the live byte counters are
         # these constants times host-side pass counts (execution-accurate
         # under jit, where kernel-level dispatch counters are trace-time).
@@ -175,13 +186,13 @@ class ServeEngine:
         # accessor sums each layer at its own width (mixed accounting)
         self._pass_bytes = weight_pass_bytes(self.params)
         self._kv_bytes_per_row = self.cfg.kv_bytes_per_token()
-        # both the residency planner and kv_bytes_per_token read the same
-        # resolved widths, so the bytes accounting cannot skew if the
-        # default ever moves
-        weight_bytes = self.cfg.n_params() * (
-            self.cfg.resolved_weight_bits // 8)
+        # the planner sizes slots around the weight bytes this engine
+        # actually holds (packed leaves at their words, plain leaves at
+        # their dtype), and kv_bytes_per_token reads the resolved KV
+        # widths, so neither side of the accounting can skew
+        held = self._pass_bytes["fused"] + self._pass_bytes["dense"]
         plan = decode_residency(
-            weight_bytes=weight_bytes,
+            weight_bytes=held,
             kv_bytes_per_token=self.cfg.kv_bytes_per_token(),
             seq_len=self.max_seq_len,
             chip=self.chip,

@@ -1,18 +1,41 @@
 """Per-kernel validation: Pallas (interpret mode) vs. pure-jnp oracle,
-sweeping shapes, widths and dtypes per the deliverable spec."""
+sweeping shapes, widths and dtypes per the deliverable spec. Pack,
+unpack and the Value Converter / Truncator have one implementation (XLA,
+through ``kernels.ops``); their cases pin it against the dense bitstream
+and the scalar format codec, which share no code with the lane-tiled
+layout."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bitpack
+from repro.core.formats import FLOAT_FORMATS, decode_float, encode_float
+from repro.kernels import ops
 from repro.kernels import ref as R
-from repro.kernels.convert import convert, truncate
 from repro.kernels.kv_decode import kv_decode
-from repro.kernels.pack import pack
 from repro.kernels.packed_matmul import packed_matmul
-from repro.kernels.unpack import unpack
 
 WIDTHS = [8, 12, 16, 20, 24, 28]
+
+
+def _stream_rows(x, bits):
+    """Each row's codes as one dense bitstream, tile by tile and lane by
+    lane — the lane-tiled layout's definition, built from the scalar
+    stream packer."""
+    codes = np.asarray(encode_float(jnp.asarray(x), FLOAT_FORMATS[bits]))
+    n = codes.shape[-1]
+    g, wg = bitpack.lane_group(bits)
+    lanes = bitpack.tile_lanes(n, bits)
+    tiles = bitpack.packed_group_words(n, bits) // (wg * lanes)
+    pad = np.zeros(codes.shape[:-1] + (tiles * g * lanes - n,), np.uint32)
+    c = np.concatenate([codes, pad], -1).reshape(
+        codes.shape[:-1] + (tiles, g, lanes)).swapaxes(-1, -2)
+    rows = [np.asarray(bitpack.pack_stream(jnp.asarray(r.reshape(-1)),
+                                           bits))
+            for r in c.reshape((-1,) + c.shape[-3:])]
+    w = np.stack(rows).reshape(codes.shape[:-1] + (tiles, lanes, wg))
+    return w.swapaxes(-1, -2).reshape(codes.shape[:-1] + (-1,))
 
 
 @pytest.mark.parametrize("bits", WIDTHS)
@@ -20,12 +43,12 @@ WIDTHS = [8, 12, 16, 20, 24, 28]
 def test_pack_unpack_vs_ref(bits, shape):
     rng = np.random.default_rng(bits)
     x = (rng.standard_normal(shape) * 4).astype(np.float32)
-    ref_p = R.pack_ref(jnp.asarray(x), bits)
-    got_p = pack(jnp.asarray(x), bits, block_rows=8, block_codes=32)
-    assert (np.asarray(got_p) == np.asarray(ref_p)).all()
-    ref_u = R.unpack_ref(ref_p, bits, shape[1])
-    got_u = unpack(got_p, bits, shape[1], block_rows=8, block_codes=32)
-    assert (np.asarray(got_u) == np.asarray(ref_u)).all()
+    got_p = ops.pack(jnp.asarray(x), bits)
+    assert (np.asarray(got_p) == _stream_rows(x, bits)).all()
+    got_u = ops.unpack(got_p, bits, shape[1])
+    want = decode_float(encode_float(jnp.asarray(x), FLOAT_FORMATS[bits]),
+                        FLOAT_FORMATS[bits])
+    assert (np.asarray(got_u) == np.asarray(want)).all()
 
 
 @pytest.mark.parametrize("bits", WIDTHS)
@@ -33,23 +56,30 @@ def test_unpack_bf16_output(bits):
     rng = np.random.default_rng(bits)
     x = rng.standard_normal((16, 64)).astype(np.float32)
     p = R.pack_ref(jnp.asarray(x), bits)
-    got = unpack(p, bits, 64, out_dtype=jnp.bfloat16,
-                 block_rows=8, block_codes=32)
-    ref = R.unpack_ref(p, bits, 64, jnp.bfloat16)
+    got = ops.unpack(p, bits, 64, out_dtype=jnp.bfloat16)
+    ref = R.unpack_ref(p, bits, 64).astype(jnp.bfloat16)
     assert got.dtype == jnp.bfloat16
     assert (np.asarray(got, np.float32) == np.asarray(ref, np.float32)).all()
 
 
 @pytest.mark.parametrize("bits", [8, 16, 24])
 def test_convert_truncate_vs_ref(bits):
+    """The Truncator/Converter lanes against the scalar codec: AF16 is
+    IEEE half, so numpy's own float16 rounding is the reference there."""
     rng = np.random.default_rng(bits)
     x = (rng.standard_normal((32, 64)) * 100).astype(np.float32)
-    codes = truncate(jnp.asarray(x), bits, block=(8, 32))
-    assert (np.asarray(codes) ==
-            np.asarray(R.truncate_ref(jnp.asarray(x), bits))).all()
-    dec = convert(codes, bits, block=(8, 32))
-    assert (np.asarray(dec) ==
-            np.asarray(R.convert_ref(codes, bits))).all()
+    codes = R.truncate_ref(jnp.asarray(x), bits)
+    dec = np.asarray(R.convert_ref(codes, bits))
+    if bits == 16:
+        assert (dec == x.astype(np.float16).astype(np.float32)).all()
+    fmt = FLOAT_FORMATS[bits]
+    normal = ((np.abs(x) >= 2.0 ** (1 - fmt.bias))
+              & (np.abs(x) < fmt.max_finite))
+    rel = np.abs(dec - x)[normal] / np.abs(x)[normal]
+    assert normal.any()
+    assert rel.max() <= 2.0 ** -(fmt.mantissa_bits + 1) * (1 + 1e-6)
+    big = np.abs(x) > 2 * fmt.max_finite
+    assert (np.isinf(dec[big]) & (np.sign(dec[big]) == np.sign(x[big]))).all()
 
 
 @pytest.mark.parametrize("bits", [8, 12, 16])
@@ -87,20 +117,17 @@ def test_packed_matmul_against_dense_matmul():
 ])
 def test_kv_decode_vs_ref(bits, cfg):
     rng = np.random.default_rng(bits)
-    q = rng.standard_normal((cfg["b"], cfg["h"], cfg["d"])
-                            ).astype(np.float32)
-    k = (rng.standard_normal((cfg["b"], cfg["s"], cfg["hkv"], cfg["d"]))
-         * 0.3).astype(np.float32)
-    v = (rng.standard_normal((cfg["b"], cfg["s"], cfg["hkv"], cfg["d"]))
-         * 0.3).astype(np.float32)
+    b, s, hkv, d = cfg["b"], cfg["s"], cfg["hkv"], cfg["d"]
+    q = rng.standard_normal((b, cfg["h"], d)).astype(np.float32)
+    k = (rng.standard_normal((b, s, hkv * d)) * 0.3).astype(np.float32)
+    v = (rng.standard_normal((b, s, hkv * d)) * 0.3).astype(np.float32)
     kp = R.pack_ref(jnp.asarray(k), bits)
     vp = R.pack_ref(jnp.asarray(v), bits)
-    lens = np.asarray(
-        rng.integers(1, cfg["s"] + 1, cfg["b"]), np.int32)
-    ref = R.kv_decode_ref(jnp.asarray(q), kp, vp, bits, cfg["d"],
+    lens = np.asarray(rng.integers(1, s + 1, b), np.int32)
+    ref = R.kv_decode_ref(jnp.asarray(q), kp, vp, bits, hkv,
                           jnp.asarray(lens))
     got = kv_decode(jnp.asarray(q), kp, vp, jnp.asarray(lens), bits,
-                    cfg["d"], block_s=cfg["block_s"])
+                    hkv, block_s=cfg["block_s"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 

@@ -225,7 +225,7 @@ def test_byte_parity_fused_vs_analytic_model():
     assert check_byte_parity(stats) == []
     assert check_byte_parity(stats, "draft_") == []
     # and the tolerance is doing work: the counters are real bytes,
-    # the model has no group-of-32 padding, so they differ but < 1%
+    # the model has no lane-tile padding, so they differ but < 1%
     want = stats["weight_passes"] * stats["fused_analytic_bytes_per_pass"]
     got = stats["weight_read_bytes_fused"]
     assert got >= want

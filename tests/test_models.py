@@ -126,3 +126,32 @@ def test_input_specs_cover_all_shapes():
             assert "tokens" in specs
             for v in specs.values():
                 assert isinstance(v, jax.ShapeDtypeStruct)
+
+
+@pytest.mark.parametrize("arch", ["qwen3_8b", "deepseek_moe_16b",
+                                  "falcon_mamba_7b", "recurrentgemma_9b",
+                                  "whisper_small", "paligemma_3b"])
+def test_init_packed_matches_repacked_init(arch):
+    """Building leaf by leaf (what lets a full-width model come up packed)
+    gives ``init``'s tree and values (to f32 rounding: each leaf's init
+    runs fused under jit), and its packed leaves are exactly those values
+    packed afterwards."""
+    from repro.core.compress import repack, uniform_plan
+
+    lm = LM(get_config(arch).reduced())
+    key = jax.random.PRNGKey(3)
+    dense = lm.init_packed(key)
+    want_l, want_t = jax.tree_util.tree_flatten(lm.init(key))
+    got_l, got_t = jax.tree_util.tree_flatten(dense)
+    assert got_t == want_t
+    for a, b in zip(got_l, want_l):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-6, atol=1e-9)
+    plan = uniform_plan(dense, 8)
+    got_l, got_t = jax.tree_util.tree_flatten(lm.init_packed(key, plan))
+    want_l, want_t = jax.tree_util.tree_flatten(repack(dense, plan))
+    assert got_t == want_t
+    for a, b in zip(got_l, want_l):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -522,15 +522,15 @@ def test_kv_decode_fully_masked_is_zero():
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.standard_normal((b, h, d)).astype(np.float32))
     # non-zero "stale garbage" in the cache
-    k = jnp.asarray((rng.standard_normal((b, s, hkv, d)) + 3.0
+    k = jnp.asarray((rng.standard_normal((b, s, hkv * d)) + 3.0
                      ).astype(np.float32))
-    v = jnp.asarray((rng.standard_normal((b, s, hkv, d)) + 3.0
+    v = jnp.asarray((rng.standard_normal((b, s, hkv * d)) + 3.0
                      ).astype(np.float32))
     kp, vp = R.pack_ref(k, 16), R.pack_ref(v, 16)
     lens = jnp.asarray(np.array([0, s], np.int32))
-    got = np.asarray(kv_decode(q, kp, vp, lens, 16, d, block_s=32,
+    got = np.asarray(kv_decode(q, kp, vp, lens, 16, hkv, block_s=32,
                                interpret=True))
-    ref = np.asarray(R.kv_decode_ref(q, kp, vp, 16, d, lens))
+    ref = np.asarray(R.kv_decode_ref(q, kp, vp, 16, hkv, lens))
     assert np.isfinite(got).all() and np.isfinite(ref).all()
     np.testing.assert_array_equal(got[0], np.zeros_like(got[0]))
     np.testing.assert_array_equal(ref[0], np.zeros_like(ref[0]))

@@ -287,26 +287,30 @@ def test_spec_for_packed_keeps_groups_intact():
     from repro.distributed.sharding import spec_for_packed
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     with compat.mesh_context(mesh):
-        # logical 128 codes = 4 full groups: a 2-way split lands on a
-        # group boundary AND matches the 64/64 logical split -> survives
+        # logical 1024 codes at AF16 = 4 tiles of 2 x 128 lanes: a 2-way
+        # split lands on a tile boundary, each half keeps 128-lane tiles
+        # AND matches the 512/512 logical split -> survives
         assert spec_for_packed(
-            "blocks/attn/wq", (64, 128),
+            "blocks/attn/wq", (64, 1024), 16,
             axis_sizes={"data": 1, "model": 2}) == P(None, "model")
-        # logical 96 codes = 3 groups: 2 shards would split a group even
-        # though the 48-word payload divides evenly -> replicate
+        # logical 128 codes at AF16 are one tile of 64-lane chunks: a
+        # split would cut the tile even though the 64-word payload
+        # divides evenly -> replicate
         assert spec_for_packed(
-            "blocks/attn/wq", (64, 96),
+            "blocks/attn/wq", (64, 128), 16,
             axis_sizes={"data": 1, "model": 2}) == P(None, None)
-        # logical 48 codes = 2 groups, but the second group is half
-        # padding: a group-boundary split would be 32/16 logically while
-        # the logical spec says 24/24 -> replicate (the rule is logical
-        # axis % (32 x shards) == 0, not group divisibility)
+        # logical 96 codes: one tile, 48 words -> replicate
         assert spec_for_packed(
-            "blocks/attn/wq", (64, 48),
+            "blocks/attn/wq", (64, 96), 16,
+            axis_sizes={"data": 1, "model": 2}) == P(None, None)
+        # logical 1536 codes at AF8 = 3 tiles of 4 x 128 lanes: tile
+        # boundaries cannot give the 768/768 logical split -> replicate
+        assert spec_for_packed(
+            "blocks/attn/wq", (64, 1536), 8,
             axis_sizes={"data": 1, "model": 2}) == P(None, None)
         # non-last axes keep the logical rules untouched
         assert spec_for_packed(
-            "blocks/attn/wo", (128, 64),
+            "blocks/attn/wo", (128, 64), 16,
             axis_sizes={"data": 1, "model": 2}) == P("model", None)
 
 
@@ -316,7 +320,7 @@ def test_shard_leaf_uses_logical_spec_for_packed():
     from repro.distributed.sharding import shard_leaf
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     rng = np.random.default_rng(0)
-    w = jnp.asarray(rng.standard_normal((64, 128)).astype(np.float32))
+    w = jnp.asarray(rng.standard_normal((64, 1024)).astype(np.float32))
     pt = pack_tensor(w, 16)
     with compat.mesh_context(mesh):
         ns = shard_leaf("blocks/mlp/w_in", pt, mesh)

@@ -18,17 +18,11 @@ HKV, D, H = 2, 32, 4          # hkv*d multiple of 32: exact group packing
 def _pools(rng, n_pages, page, bits):
     """Physical pools filled with *encoded real values* (random words
     can decode to NaN codes, and 0 * NaN poisons the masked rows)."""
-    kf = rng.standard_normal((n_pages, page, HKV, D)).astype(np.float32)
-    vf = rng.standard_normal((n_pages, page, HKV, D)).astype(np.float32)
+    kf = rng.standard_normal((n_pages, page, HKV * D)).astype(np.float32)
+    vf = rng.standard_normal((n_pages, page, HKV * D)).astype(np.float32)
     if bits:
-        w = D * bits // 32
-        pk = kref.pack_ref(
-            jnp.asarray(kf.reshape(n_pages, page, -1)), bits
-        ).reshape(n_pages, page, HKV, w)
-        pv = kref.pack_ref(
-            jnp.asarray(vf.reshape(n_pages, page, -1)), bits
-        ).reshape(n_pages, page, HKV, w)
-        return pk, pv
+        return (kref.pack_ref(jnp.asarray(kf), bits),
+                kref.pack_ref(jnp.asarray(vf), bits))
     return jnp.asarray(kf), jnp.asarray(vf)
 
 
@@ -56,9 +50,9 @@ def test_kernel_matches_oracle_interpret(bits, page):
     rng = np.random.default_rng(7 * page + bits)
     lens = [0, 1, page - 1, page, page + 1, 3 * page]
     q, pk, pv, table, kv_len = _case(rng, page, bits, lens)
-    got = paged_attention(q, pk, pv, table, kv_len, bits, D,
+    got = paged_attention(q, pk, pv, table, kv_len, bits, HKV,
                           interpret=True)
-    want = kref.paged_attention_ref(q, pk, pv, table, kv_len, bits, D)
+    want = kref.paged_attention_ref(q, pk, pv, table, kv_len, bits, HKV)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -75,25 +69,23 @@ def test_scrap_page_never_leaks(bits):
     lens = [1, page, 2 * page - 1]
     q, pk, pv, table, kv_len = _case(rng, page, bits, lens)
     if bits:
-        w = D * bits // 32
         poison = kref.pack_ref(
-            jnp.full((1, page, HKV * D), 10.0, jnp.float32), bits
-        ).reshape(1, page, HKV, w)
+            jnp.full((1, page, HKV * D), 10.0, jnp.float32), bits)
         assert np.isfinite(np.asarray(kref.unpack_ref(
-            poison.reshape(1, page, -1), bits, HKV * D))).all()
+            poison, bits, HKV * D))).all()
     else:
-        poison = jnp.full((1, page, HKV, D), 1e4, jnp.float32)
+        poison = jnp.full((1, page, HKV * D), 1e4, jnp.float32)
     pk_p = pk.at[0].set(poison[0])
     pv_p = pv.at[0].set(poison[0])
-    clean = paged_attention(q, pk, pv, table, kv_len, bits, D,
+    clean = paged_attention(q, pk, pv, table, kv_len, bits, HKV,
                             interpret=True)
-    dirty = paged_attention(q, pk_p, pv_p, table, kv_len, bits, D,
+    dirty = paged_attention(q, pk_p, pv_p, table, kv_len, bits, HKV,
                             interpret=True)
     assert (np.asarray(clean) == np.asarray(dirty)).all()
     ref_clean = kref.paged_attention_ref(q, pk, pv, table, kv_len,
-                                         bits, D)
+                                         bits, HKV)
     ref_dirty = kref.paged_attention_ref(q, pk_p, pv_p, table, kv_len,
-                                         bits, D)
+                                         bits, HKV)
     assert (np.asarray(ref_clean) == np.asarray(ref_dirty)).all()
 
 

@@ -187,3 +187,35 @@ def test_residency_planner_monotone_in_bits():
     assert r16.max_sequences > 0
     assert r8.max_sequences >= 2 * r16.max_sequences - 1
     assert r8.arithmetic_intensity > r16.arithmetic_intensity
+
+
+def test_chip_constants_keyed_by_device_kind():
+    """The planner reads its chip from the device: v5e by kind, the
+    reference v5e off-TPU, and an unknown TPU kind is an error."""
+    import types
+
+    from repro.core.occupancy import CHIPS, TPU_V5E, chip_for_device
+
+    def dev(platform, kind):
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    assert chip_for_device(dev("tpu", "TPU v5 lite")) is TPU_V5E
+    assert chip_for_device(dev("cpu", "cpu")) is TPU_V5E
+    assert all(c.source for c in CHIPS.values())
+    with pytest.raises(KeyError, match="TPU v99"):
+        chip_for_device(dev("tpu", "TPU v99"))
+
+
+def test_engine_residency_reads_held_weight_bytes():
+    """Slots are planned around the bytes the engine actually holds: at
+    AF8 the packed weights are smaller, so at least as many slots fit."""
+    cfg = get_config("qwen3_8b").reduced()
+    e16 = ServeEngine(cfg, max_seq_len=32, pack_weights=True)
+    cfg8 = dataclasses.replace(cfg, compression=dataclasses.replace(
+        cfg.compression, weight_bits=8))
+    e8 = ServeEngine(cfg8, max_seq_len=32, pack_weights=True)
+    held16 = e16._pass_bytes["fused"] + e16._pass_bytes["dense"]
+    held8 = e8._pass_bytes["fused"] + e8._pass_bytes["dense"]
+    assert held8 < held16
+    assert e8.residency.weight_bytes == held8
+    assert e16.residency.weight_bytes == held16
