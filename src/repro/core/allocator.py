@@ -20,7 +20,7 @@ import collections
 import dataclasses
 import hashlib
 import math
-from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -348,20 +348,11 @@ class KVPagePool:
         self.prefix_queries = 0
         # lifecycle telemetry: monotone per-pool event counts ("evict" =
         # a last free returning the page to the pool), mirrored into the
-        # engine's metrics snapshot. ``on_event`` is an optional
-        # span/event hook — the serving engine wires it to its tracer
-        # (``on_event(name, **attrs)``) so page churn shows up in the
-        # JSONL stream; the pool itself stays import-clean of obs.
+        # engine's metrics snapshot as ``pool_<event>_total``
         self.events: Dict[str, int] = {
             "alloc": 0, "free": 0, "retain": 0, "evict": 0,
             "reserve": 0, "release": 0,
         }
-        self.on_event: Optional[Callable[..., object]] = None
-
-    def _event(self, name: str, count: int = 1, **attrs) -> None:
-        self.events[name] += count
-        if self.on_event is not None:
-            self.on_event(f"kv_pool.{name}", **attrs)
 
     # -- capacity accounting --------------------------------------------------
     @property
@@ -398,8 +389,7 @@ class KVPagePool:
                 f"{self.n_pages} ({self.used} used, {self._reserved} "
                 "reserved)")
         self._reserved += n
-        if n:
-            self._event("reserve", n, pages=n, reserved=self._reserved)
+        self.events["reserve"] += n
 
     def release(self, n: int) -> None:
         """Return unallocated reservation (request finished early)."""
@@ -407,8 +397,7 @@ class KVPagePool:
             raise ValueError(
                 f"cannot release {n} of {self._reserved} reserved pages")
         self._reserved -= n
-        if n:
-            self._event("release", n, pages=n, reserved=self._reserved)
+        self.events["release"] += n
 
     # -- allocate / free ------------------------------------------------------
     def alloc(self, reserved: bool = False) -> int:
@@ -428,7 +417,7 @@ class KVPagePool:
         page = self._free.popleft()
         self._refcount[page] = 1
         self.peak_used = max(self.peak_used, self.used)
-        self._event("alloc", page=page, reserved=reserved)
+        self.events["alloc"] += 1
         return page
 
     def retain(self, page: int) -> None:
@@ -436,7 +425,7 @@ class KVPagePool:
         if page not in self._refcount:
             raise ValueError(f"retain of unallocated page {page}")
         self._refcount[page] += 1
-        self._event("retain", page=page, refcount=self._refcount[page])
+        self.events["retain"] += 1
 
     def free(self, page: int) -> None:
         """Drop one holder; the last free returns the page to the pool
@@ -448,15 +437,15 @@ class KVPagePool:
                 f"free of unallocated page {page} (double free?)")
         if rc > 1:
             self._refcount[page] = rc - 1
-            self._event("free", page=page, refcount=rc - 1)
+            self.events["free"] += 1
             return
         del self._refcount[page]
         key = self._page_key.pop(page, None)
         if key is not None:
             self._registry.pop(key, None)
         self._free.append(page)
-        self._event("free", page=page, refcount=0)
-        self._event("evict", page=page, registered=key is not None)
+        self.events["free"] += 1
+        self.events["evict"] += 1
 
     def refcount(self, page: int) -> int:
         return self._refcount.get(page, 0)

@@ -221,6 +221,7 @@ def _packed_call(x3, w3, bits, n, transpose, bm, bn, bk, out_dtype,
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
+        name="packed_matmul",
     )(x3, w3)
     return out[..., :m, :n]
 

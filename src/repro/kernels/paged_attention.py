@@ -140,5 +140,6 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, dim), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(flat_table, kv_len.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, h, dim)

@@ -750,6 +750,14 @@ class LM:
         un-folded, so they cannot serve as speculation targets."""
         return self.cfg.family in ("dense", "moe", "vlm", "encdec")
 
+    def weight_passes(self, positions: int) -> int:
+        """Passes over the weights that one ``verify_step`` or
+        ``prefill_step`` call over ``positions`` token positions streams:
+        the call scans the single-token decode step once per position, so
+        every position reads every weight. The engines' byte counters
+        multiply their per-pass bytes by this."""
+        return positions
+
     def verify_step(self, params, state: Dict,
                     tokens: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
         """tokens: (B, T) -> (logits (B, T, V), state with len advanced T).

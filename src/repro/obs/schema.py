@@ -36,6 +36,10 @@ SNAPSHOT_KEYS_BASE = frozenset({
     "fused_bytes_per_pass", "fused_analytic_bytes_per_pass",
     "fused_f32_bytes_per_pass", "dense_bytes_per_pass",
     "kv_rows_appended", "kv_rows_committed", "kv_bytes_appended",
+    "prefill_tokens", "prefill_weight_passes",
+    "host_prepare_s", "host_table_s", "host_dispatch_s", "host_wait_s",
+    "host_commit_s", "host_admit_s",
+    "gc_collections", "gc_pause_s",
 })
 
 #: additional keys when ``paged=True`` (the KVPagePool view)

@@ -21,14 +21,22 @@ for every span so spans line up with XLA activity in a profiler trace;
 it is feature-detected and silently off when unavailable (the module
 itself never imports jax at import time — the obs layer stays
 dependency-free).
+
+``watch_gc()`` records every garbage collection of the process as a
+``GC_SPAN`` span (``generation``, ``collected``) and keeps running totals
+of the collections and the seconds they paused the interpreter
+(``gc_collections``, ``gc_pause_ns``), whether or not anyone reads the
+ring.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import json
 import threading
 import time
+import weakref
 from typing import Any, Deque, Dict, IO, List, Optional, Union
 
 
@@ -44,6 +52,44 @@ def _json_default(o: Any) -> Any:
         return str(o)
 
 
+#: the span name of one garbage collection (``Tracer.watch_gc``)
+GC_SPAN = "serve.gc"
+
+
+class _GcHook:
+    """The ``gc.callbacks`` entry of one tracer. It holds the tracer
+    weakly, so a tracer that is gone leaves a hook that does nothing
+    (and that the next ``watch_gc`` call removes)."""
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer = weakref.ref(tracer)
+        self._t0 = 0
+        self._ts = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        tracer = self.tracer()
+        if tracer is None:
+            return
+        if phase == "start":
+            self._ts = time.time()
+            if tracer._annotation is not None:
+                self._ann = tracer._annotation(GC_SPAN)
+                self._ann.__enter__()
+            self._t0 = time.perf_counter_ns()
+            return
+        dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        tracer.gc_pause_ns += dur
+        tracer.gc_collections += 1
+        tracer._emit({"kind": "span", "name": GC_SPAN, "ts": self._ts,
+                      "dur_s": dur * 1e-9,
+                      "attrs": {"generation": info.get("generation"),
+                                "collected": info.get("collected")}})
+
+
 class Tracer:
     """Ring-buffered span/event recorder with an optional JSONL sink."""
 
@@ -52,10 +98,15 @@ class Tracer:
                  annotate: bool = False):
         self._ring: Deque[Dict[str, Any]] = collections.deque(
             maxlen=ring_capacity)
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start inside _emit, and
+        # the gc hook emits its own record from the same thread
+        self._lock = threading.RLock()
         self._sink: Optional[IO[str]] = None
         self._owns_sink = False
         self.dropped = 0          # records emitted after the sink failed
+        self.gc_collections = 0   # running totals kept by watch_gc
+        self.gc_pause_ns = 0
+        self._gc_hook: Optional[_GcHook] = None
         self._annotation = None
         if annotate:
             try:
@@ -124,6 +175,18 @@ class Tracer:
             yield live
         self._emit({"kind": "span", "name": name, "ts": ts,
                     "dur_s": time.perf_counter() - t0, "attrs": live})
+
+    def watch_gc(self) -> None:
+        """Record each garbage collection from now on as a ``GC_SPAN``
+        span and add it to ``gc_collections`` / ``gc_pause_ns``. One hook per
+        tracer: later calls do nothing."""
+        if self._gc_hook is not None:
+            return
+        gc.callbacks[:] = [cb for cb in gc.callbacks
+                           if not (isinstance(cb, _GcHook)
+                                   and cb.tracer() is None)]
+        self._gc_hook = _GcHook(self)
+        gc.callbacks.append(self._gc_hook)
 
     # -- inspection -----------------------------------------------------------
     def events(self, name: Optional[str] = None) -> List[Dict[str, Any]]:

@@ -23,6 +23,10 @@ batch -> each weight read amortized over more tokens -> tokens/s up.
     it with a draft-propose / full-width-verify tick that commits several
     tokens per call.
 
+Each tick's host work is timed in phases (``TICK_PHASES``): a tracer
+span each, and a running total of nanoseconds that ``metrics_snapshot()``
+reports as ``host_<phase>_s`` whether or not anyone reads the spans.
+
 ``pack_weights=True`` packs every matmul-eligible weight at the config's
 planned width (``core.compress.uniform_plan`` over the parameter shapes,
 packed leaf by leaf in ``LM.init_packed``), putting the
@@ -57,6 +61,14 @@ from repro.models.config import ModelConfig
 from repro.models.lm import LM
 
 
+#: host phases of a serving tick, in order: build the step's tokens and
+#: page bookkeeping; push the page table; enqueue the step; block on its
+#: sampled tokens; commit them (append, finish, evict); admit queued
+#: requests and dispatch their prefill. ``admit`` also counts admissions
+#: that ``submit()`` runs outside a tick.
+TICK_PHASES = ("prepare", "table", "dispatch", "wait", "commit", "admit")
+
+
 def _pool_copy_page(kv, src: int, dst: int):
     """Copy one physical page across every layer buffer of a KV pool —
     the single stacked ``{"k", "v"}`` dict, or the tuple of per-segment
@@ -89,7 +101,11 @@ class Request:
     output: List[int] = dataclasses.field(default_factory=list)
     slot: Optional[int] = None
     done: bool = False
+    # time.perf_counter() stamps: submit(), admission to a slot, the
+    # tick that committed the first output token, the tick that finished
     submitted_at: float = 0.0
+    admitted_at: float = 0.0
+    first_token_at: float = 0.0
     finished_at: float = 0.0
     # speculative per-request acceptance stats (0/0 on the plain engine)
     draft_proposed: int = 0
@@ -148,6 +164,7 @@ class ServeEngine:
     def __post_init__(self):
         if self.tracer is None:
             self.tracer = obs.default_tracer()
+        self.tracer.watch_gc()
         # a plan carrying per-layer KV widths (the static analysis pass's
         # activation-width family) rewrites the config before the LM is
         # built: uniform widths normalize to the scalar knob (the exact
@@ -216,7 +233,6 @@ class ServeEngine:
             if self.kv_pool_pages is None:
                 self.kv_pool_pages = self.n_slots * self._max_pages
             self.pool = KVPagePool(self.kv_pool_pages, self.kv_page_size)
-            self.pool.on_event = self.tracer.event
             # The authoritative page table is DEVICE-resident: it rides
             # through every donated jitted call inside the state dict, so
             # it survives donation. The host copy here is a *shadow* for
@@ -262,6 +278,8 @@ class ServeEngine:
         self._decode_calls = 0
         self._prefill_calls = 0
         self._weight_passes = 0
+        self._prefill_tokens = 0
+        self._prefill_weight_passes = 0
         self._kv_rows_appended = 0
         self._kv_rows_committed = 0
         self._finished_total = 0
@@ -273,6 +291,10 @@ class ServeEngine:
         self._table_rows_uploaded = 0
         self._kv_pages_read = 0
         self._kv_pages_read_dense_equiv = 0
+        # host nanoseconds per tick phase; _mark closes a phase at the
+        # clock read that opens the next one
+        self._host_ns = dict.fromkeys(TICK_PHASES, 0)
+        self._lap_ns = 0
         # Sampling key derivation: base = PRNGKey(tag) folded with a
         # per-engine nonce, then per tick fold in the tick counter and per
         # slot the slot index. Without the nonce a restarted engine
@@ -321,7 +343,9 @@ class ServeEngine:
             rid=rid, prompt=list(prompt), max_new_tokens=max_new_tokens,
             submitted_at=time.perf_counter(),
         ))
-        self._admit()
+        self._lap_ns = time.perf_counter_ns()
+        with self._phase("admit", "serve.admission"):
+            self._admit()
         return rid
 
     def result(self, rid: int) -> Optional[List[int]]:
@@ -367,7 +391,8 @@ class ServeEngine:
             req.slot = slot
             self._active[req.rid] = req
             admitted = True
-            wait = time.perf_counter() - req.submitted_at
+            req.admitted_at = time.perf_counter()
+            wait = req.admitted_at - req.submitted_at
             self._admitted_total += 1
             self._admission_wait_sum += wait
             obs.REGISTRY.histogram(
@@ -606,10 +631,6 @@ class ServeEngine:
         self._table_uploads += 1
         self._table_upload_bytes += nbytes
         self._table_rows_uploaded += rows_dirty
-        obs.REGISTRY.counter(
-            "serve_table_rows_uploaded_total",
-            "Dirty page-table rows uploaded to the device table.",
-        ).inc(rows_dirty)
 
     def _apply_table_update(self, idx, rows) -> None:
         """Apply one table delta (or a full refresh when ``idx`` is
@@ -622,42 +643,46 @@ class ServeEngine:
             self.state["table"] = self._table_scatter(
                 self.state["table"], jnp.asarray(idx), jnp.asarray(rows))
 
-    def _count_pages_read(self, len0s, positions: int) -> Optional[int]:
+    def _count_pages_read(self, len0s, positions: int) -> Dict[str, int]:
         """Analytic pages the fused paged-attention path touches in one
         jitted call that walks ``positions`` KV-append steps: at step i
         a resident slot with ``len0`` committed rows attends over
         ``ceil((len0 + i) / page_size)`` live pages (one *logical* page
         spans every layer's K and V rows for those positions — the same
         convention as ``kv_bytes_per_token``). Dead slots sit on the
-        scrap page, which the kernel's revisit elision dedupes. Returns
-        None when the call does not attend through the table (dense
-        mode, or the gather oracle — which always reads
-        slots x max_pages); also accrues the dense-equivalent figure so
-        the pages-actually-live win is reportable."""
+        scrap page, which the kernel's revisit elision dedupes. Accrues
+        them, and the dense-equivalent figure so the pages-actually-live
+        win is reportable, and returns both with ``positions`` as the
+        attributes of the call's span. Empty when the call does not
+        attend through the table (dense mode, or the gather oracle —
+        which always reads slots x max_pages)."""
         if not (self.paged and self.paged_attn):
-            return None
+            return {}
         pg = self.kv_page_size
         pages = 0
         for len0 in len0s:
             for i in range(1, positions + 1):
                 pages += -(-min(len0 + i, self.max_seq_len) // pg)
+        dense = positions * self.n_slots * self._max_pages
         self._kv_pages_read += pages
-        self._kv_pages_read_dense_equiv += (
-            positions * self.n_slots * self._max_pages)
-        obs.REGISTRY.counter(
-            "kv_pages_read_total",
-            "KV pool pages the fused paged-attention path reads.",
-        ).inc(pages)
-        return pages
+        self._kv_pages_read_dense_equiv += dense
+        return {"pages": pages, "positions": positions,
+                "dense_equiv_pages": dense}
 
-    def _paged_attn_span(self, pages: Optional[int], positions: int):
-        """Span around a fused paged-attention call (no-op context when
-        the call is not fused-paged)."""
-        if pages is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(
-            "serve.paged_attn", pages=pages, positions=positions,
-            dense_equiv_pages=positions * self.n_slots * self._max_pages)
+    @contextlib.contextmanager
+    def _phase(self, phase: str, span: str, **attrs):
+        """Host phase ``phase`` of serving as the tracer span ``span``,
+        closed by :meth:`_mark` when the span ends."""
+        with self.tracer.span(span, **attrs) as live:
+            yield live
+        self._mark(phase)
+
+    def _mark(self, phase: str) -> None:
+        """End host phase ``phase`` here: the nanoseconds since the
+        previous mark (or the tick's start) add to its running total."""
+        now = time.perf_counter_ns()
+        self._host_ns[phase] += now - self._lap_ns
+        self._lap_ns = now
 
     def _ingest_prompts(self) -> None:
         """Stream pending prompts through ``lm.prefill_step`` in chunks of
@@ -703,10 +728,10 @@ class ServeEngine:
             rows = int(n_valid.sum())
             self._kv_rows_appended += rows
             self._kv_rows_committed += rows
+            self._prefill_tokens += rows
             pages = self._count_pages_read(len0s, chunk)
             with self.tracer.span("serve.prefill", chunk=chunk, rows=rows,
-                                  requests=len(pending)), \
-                    self._paged_attn_span(pages, chunk):
+                                  requests=len(pending), **pages):
                 self._prefill_call(jnp.asarray(tokens),
                                    jnp.asarray(n_valid))
 
@@ -714,8 +739,10 @@ class ServeEngine:
                       n_valid: jnp.ndarray) -> None:
         """One chunked KV-append over the slot array. Overridable — the
         speculative engine mirrors every chunk into its draft cache."""
+        passes = self.lm.weight_passes(tokens.shape[1])
         self._prefill_calls += 1
-        self._weight_passes += 1
+        self._prefill_weight_passes += passes
+        self._weight_passes += passes
         self.state = self._prefill(self.params, self.state, tokens, n_valid)
 
     def _tick_key(self, salt: int = 0):
@@ -731,37 +758,49 @@ class ServeEngine:
         engine restarts (the per-engine nonce)."""
         return sample_per_slot(self._tick_key(), logits)
 
-    def _generate(self) -> Dict[int, List[int]]:
-        """One decode tick: returns the tokens committed per request id.
-        The pluggable stepper — ``SpeculativeEngine`` replaces this with a
-        draft/verify tick that can commit up to k+1 tokens per request."""
-        tokens = np.array(self._last_tokens)     # writable host copy
-        for req in self._active.values():
-            pend = self._pending_prefill.get(req.rid)
-            if pend:
-                tokens[req.slot, 0] = pend.pop(0)
-        if self.paged:
-            # every resident slot appends one row this tick
+    def _generate(self) -> Any:
+        """One decode step, through the ``prepare``, ``table``,
+        ``dispatch`` and ``wait`` phases of the tick: returns what the
+        step sampled, on the host, for :meth:`_commit`. With ``_commit``
+        the pluggable stepper — ``SpeculativeEngine`` replaces both with
+        a draft/verify tick that can commit up to k+1 tokens per
+        request."""
+        with self._phase("prepare", "serve.prepare"):
+            tokens = np.array(self._last_tokens)     # writable host copy
             for req in self._active.values():
-                self._ensure_rows(req, req.kv_len + 1)
+                pend = self._pending_prefill.get(req.rid)
+                if pend:
+                    tokens[req.slot, 0] = pend.pop(0)
+            if self.paged:
+                # every resident slot appends one row this tick
+                for req in self._active.values():
+                    self._ensure_rows(req, req.kv_len + 1)
+            rows = len(self._active)
+            pages = self._count_pages_read(
+                [r.kv_len for r in self._active.values()], 1)
+            self._decode_calls += 1
+            self._weight_passes += self.lm.weight_passes(1)
+            self._kv_rows_appended += rows
+            self._kv_rows_committed += rows
+            toks = jnp.asarray(tokens)
+        if self.paged:
             self._push_tables()
-        toks = jnp.asarray(tokens)
-        self._decode_calls += 1
-        self._weight_passes += 1
-        rows = len(self._active)
-        self._kv_rows_appended += rows
-        self._kv_rows_committed += rows
-        pages = self._count_pages_read(
-            [r.kv_len for r in self._active.values()], 1)
-        with self.tracer.span("serve.decode", requests=rows), \
-                self._paged_attn_span(pages, 1):
+        self._mark("table")
+        with self._phase("dispatch", "serve.decode", requests=rows,
+                         **pages):
             logits, self.state = self._step(self.params, self.state, toks)
+            nxt = (jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+                   if self.greedy
+                   else self._sample_tokens(logits[:, 0, :]))
+        with self._phase("wait", "serve.wait"):
+            return np.asarray(nxt)
+
+    def _commit(self, nxt: Any) -> Dict[int, List[int]]:
+        """The tokens :meth:`_generate`'s step commits, per request id,
+        and the device-side state for the next step."""
         if self.paged:
             for req in self._active.values():
                 req.kv_len = min(req.kv_len + 1, self.max_seq_len)
-        nxt = (jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
-               if self.greedy else self._sample_tokens(logits[:, 0, :]))
-        nxt = np.asarray(nxt)
         out: Dict[int, List[int]] = {}
         for req in self._active.values():
             if self._pending_prefill.get(req.rid):
@@ -775,39 +814,58 @@ class ServeEngine:
         emitted to finished outputs this tick."""
         if not self._active:
             return 0
+        self._lap_ns = time.perf_counter_ns()
         with self.tracer.span("serve.tick", tick=self.ticks) as sp:
-            committed = self._generate()
-            emitted = 0
-            finished: List[int] = []
-            for rid, toks in committed.items():
-                req = self._active[rid]
-                room = req.max_new_tokens - len(req.output)
-                take = toks[:room]
-                req.output.extend(take)
-                emitted += len(take)
-                if len(req.output) >= req.max_new_tokens:
-                    req.done = True
-                    req.finished_at = time.perf_counter()
-                    finished.append(rid)
-            for rid in finished:           # evict: _active stays bounded
-                req = self._active.pop(rid)
-                self._results[rid] = req.output
-                if self.paged:
-                    self._release_pages(req)  # pages to the pool first,
-                self._free.append(req.slot)   # then the slot: occupancy
-                self._pending_prefill.pop(rid, None)
-            self._finished_total += len(finished)
-            while len(self._results) > self.max_results:
-                self._results.pop(next(iter(self._results)))
-            self._admit()
+            sampled = self._generate()
+            with self._phase("commit", "serve.commit"):
+                emitted, finished = self._commit_outputs(
+                    self._commit(sampled))
+            with self._phase("admit", "serve.admission"):
+                self._admit()
             self.ticks += 1
             self.tokens_out += emitted
             sp["emitted"] = emitted
-            sp["finished"] = len(finished)
+            sp["finished"] = finished
         if self.metrics_interval and (
                 self.ticks % self.metrics_interval == 0):
             self._emit_metrics()
         return emitted
+
+    def _commit_outputs(self, committed: Dict[int, List[int]]):
+        """Append each request's committed tokens to its output, up to
+        its budget; finish and evict the requests that reached it.
+        Returns (tokens emitted, requests finished)."""
+        now = time.perf_counter()
+        emitted = 0
+        finished: List[int] = []
+        for rid, toks in committed.items():
+            req = self._active[rid]
+            take = toks[:req.max_new_tokens - len(req.output)]
+            if take and not req.output:
+                req.first_token_at = now
+            req.output.extend(take)
+            emitted += len(take)
+            if len(req.output) >= req.max_new_tokens:
+                req.done = True
+                req.finished_at = now
+                finished.append(rid)
+        for rid in finished:           # evict: _active stays bounded
+            req = self._active.pop(rid)
+            self._results[rid] = req.output
+            if self.paged:
+                self._release_pages(req)  # pages to the pool first,
+            self._free.append(req.slot)   # then the slot: occupancy
+            self._pending_prefill.pop(rid, None)
+            self.tracer.event(
+                "serve.request", rid=rid, prompt_len=len(req.prompt),
+                output_len=len(req.output),
+                queue_wait_s=req.admitted_at - req.submitted_at,
+                ttft_s=req.first_token_at - req.submitted_at,
+                e2e_s=req.finished_at - req.submitted_at)
+        self._finished_total += len(finished)
+        while len(self._results) > self.max_results:
+            self._results.pop(next(iter(self._results)))
+        return emitted, len(finished)
 
     # -- observability --------------------------------------------------------
     def metrics_snapshot(self) -> Dict[str, Any]:
@@ -846,7 +904,13 @@ class ServeEngine:
             "kv_rows_committed": self._kv_rows_committed,
             "kv_bytes_appended":
                 self._kv_rows_appended * self._kv_bytes_per_row,
+            "prefill_tokens": self._prefill_tokens,
+            "prefill_weight_passes": self._prefill_weight_passes,
+            "gc_collections": self.tracer.gc_collections,
+            "gc_pause_s": self.tracer.gc_pause_ns * 1e-9,
         }
+        for phase, ns in self._host_ns.items():
+            snap[f"host_{phase}_s"] = ns * 1e-9
         if self.pool is not None:
             ev = self.pool.events
             snap.update({

@@ -310,62 +310,72 @@ class SpeculativeEngine(ServeEngine):
         return draft_fn
 
     # -- the speculative stepper ---------------------------------------------
-    def _generate(self) -> Dict[int, List[int]]:
-        tokens = np.array(self._last_tokens)
-        for req in self._active.values():
-            pend = self._pending_prefill.get(req.rid)
-            if pend:
-                # chunked ingestion left exactly one token: the slot's
-                # first real input. It feeds both models this tick.
-                tokens[req.slot, 0] = pend.pop(0)
-        if self.paged:
-            # peak rows this tick: k+1 appends (draft and target alike)
-            # from the committed length, before the roll-back
+    def _generate(self) -> Any:
+        with self._phase("prepare", "serve.prepare"):
+            tokens = np.array(self._last_tokens)
             for req in self._active.values():
-                self._ensure_rows(req, min(req.kv_len + self.k + 1,
-                                           self.max_seq_len))
+                pend = self._pending_prefill.get(req.rid)
+                if pend:
+                    # chunked ingestion left exactly one token: the slot's
+                    # first real input. It feeds both models this tick.
+                    tokens[req.slot, 0] = pend.pop(0)
+            if self.paged:
+                # peak rows this tick: k+1 appends (draft and target
+                # alike) from the committed length, before the roll-back
+                for req in self._active.values():
+                    self._ensure_rows(req, min(req.kv_len + self.k + 1,
+                                               self.max_seq_len))
+            t0 = jnp.asarray(tokens)
+            len0 = np.asarray(self.state["len"]).astype(np.int64)
+            dlen0 = np.asarray(self.draft_state["len"]).astype(np.int64)
+            # the draft scan runs k single-token decodes plus one extra
+            # append (d_k's KV row): k+1 passes over the draft weights
+            self._count_draft_passes(self.k + 1)
+            self._decode_calls += 1
+            self._weight_passes += self.lm.weight_passes(self.k + 1)
+            # fused-paged verify walks k+1 appended positions through the
+            # target's page table; the draft's (narrower) pool reads ride
+            # the same tables and are not double-counted here
+            pages = self._count_pages_read(
+                [r.kv_len for r in self._active.values()], self.k + 1)
+            peak_rows = (self.k + 1) * len(self._active)
+            self._kv_rows_appended += peak_rows
+            self._draft_kv_rows_appended += peak_rows
+        if self.paged:
             self._push_tables()
-        t0 = jnp.asarray(tokens)
-        len0 = np.asarray(self.state["len"]).astype(np.int64)
-        dlen0 = np.asarray(self.draft_state["len"]).astype(np.int64)
+        self._mark("table")
 
-        # draft stream salted off the engine's sampling base: unique per
+        # dispatch: the draft's k+1 steps, then the verify. The draft
+        # stream is salted off the engine's sampling base: unique per
         # (engine nonce, tick). The salt sits far above any slot index so
         # the draft key can never coincide with a per-slot sampling key
         # derived from the same tick key.
         key = self._tick_key(salt=0x0D4AF7)
-        # the draft scan runs k single-token decodes plus one extra
-        # append (d_k's KV row): k+1 passes over the draft weights
-        self._count_draft_passes(self.k + 1)
         with self.tracer.span("serve.draft", k=self.k,
                               bits=self.draft_bits):
             drafts, dlogits, self.draft_state = self._draft_k(
                 self.draft_params, self.draft_state, t0, key)
         vt = jnp.concatenate([t0, drafts], axis=1)       # (B, k+1)
-        self._decode_calls += 1
-        self._weight_passes += 1                 # one full-width verify
-        # fused-paged verify walks k+1 appended positions through the
-        # target's page table; the draft's (narrower) pool reads ride the
-        # same tables and are not double-counted here
-        pages = self._count_pages_read(
-            [r.kv_len for r in self._active.values()], self.k + 1)
-        with self.tracer.span("serve.verify", positions=self.k + 1), \
-                self._paged_attn_span(pages, self.k + 1):
+        with self.tracer.span("serve.verify",
+                              **{"positions": self.k + 1, **pages}):
             vlogits, self.state = self._verify(self.params, self.state, vt)
-        peak_rows = (self.k + 1) * len(self._active)
-        self._kv_rows_appended += peak_rows
-        self._draft_kv_rows_appended += peak_rows
+            if self.greedy:
+                # device-side argmax: bit-identical to the plain engine's
+                # sampling rule on bit-identical logits
+                cand = jnp.argmax(vlogits, axis=-1)           # (B, k+1)
+        self._mark("dispatch")
 
-        drafts_np = np.asarray(drafts)
-        if self.greedy:
-            # device-side argmax: bit-identical to the plain engine's
-            # sampling rule on bit-identical logits
-            cand = np.asarray(jnp.argmax(vlogits, axis=-1))  # (B, k+1)
-            commit = self._accept_greedy(drafts_np, cand)
-        else:
-            commit = self._accept_sampled(drafts_np, drafts, vlogits,
-                                          dlogits)
+        with self._phase("wait", "serve.wait"):
+            drafts_np = np.asarray(drafts)
+            if self.greedy:
+                commit = self._accept_greedy(drafts_np, np.asarray(cand))
+            else:
+                commit = self._accept_sampled(drafts_np, drafts, vlogits,
+                                              dlogits)
+        return commit, len0, dlen0
 
+    def _commit(self, sampled: Any) -> Dict[int, List[int]]:
+        commit, len0, dlen0 = sampled
         out: Dict[int, List[int]] = {}
         commits = np.zeros((self.n_slots,), np.int64)
         last = np.array(self._last_tokens)
@@ -542,7 +552,8 @@ class SpeculativeEngine(ServeEngine):
     def _prefill_call(self, tokens: jnp.ndarray,
                       n_valid: jnp.ndarray) -> None:
         super()._prefill_call(tokens, n_valid)
-        self._count_draft_passes(1)
+        self._count_draft_passes(
+            self.draft_lm.weight_passes(tokens.shape[1]))
         self._draft_kv_rows_appended += int(np.asarray(n_valid).sum())
         self.draft_state = self._draft_prefill(
             self.draft_params, self.draft_state, tokens, n_valid)

@@ -3,6 +3,7 @@ stability contract (exact snapshot and drain key sets per engine mode),
 mid-run snapshot purity, the byte-accounting parity invariant (live
 counters vs. the analytic bits/32 model, and vs. the packed-path bench
 artifact), and the JSONL stream validator end-to-end."""
+import gc
 import json
 import math
 import os
@@ -22,8 +23,9 @@ from repro.obs.schema import (
     snapshot_keys,
     validate_metrics_jsonl,
 )
-from repro.obs.trace import Tracer
+from repro.obs.trace import GC_SPAN, Tracer
 from repro.serving import ServeEngine, SpeculativeEngine
+from repro.serving.engine import TICK_PHASES
 
 
 def _tiny_cfg(name="qwen3_8b"):
@@ -185,14 +187,128 @@ def test_snapshot_and_drain_schema_speculative():
 
 
 def test_drain_reuses_snapshot_counters():
-    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2)
+    tracer = Tracer()
+    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
+                      tracer=tracer)
     _, stats = _drain_engine(eng)
     snap = eng.metrics_snapshot()
     for key, val in snap.items():
+        if key.startswith("gc_"):
+            continue      # process-wide: a collection may fall between
         assert stats[key] == val, key
     assert stats["wall_s"] > 0
-    assert stats["weight_passes"] == (
-        stats["decode_calls"] + stats["prefill_calls"])
+    # a prefill chunk's scan streams the weights once per position
+    positions = sum(r["attrs"]["chunk"]
+                    for r in tracer.events("serve.prefill"))
+    assert stats["prefill_calls"] > 0
+    assert stats["weight_passes"] == stats["decode_calls"] + positions
+
+
+# -- the serving tick: host phases, request stamps, true weight passes -------
+
+_PHASE_SPANS = ("serve.prepare", "serve.decode", "serve.wait",
+                "serve.commit", "serve.admission")
+
+
+def test_tick_phases_sum_to_the_tick():
+    """The running totals of the host phases grow, in each tick, by that
+    tick's ``serve.tick`` span; each phase is a span of its own inside
+    it; the engine waits on the device in every tick."""
+    tracer = Tracer(ring_capacity=1 << 16)
+    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
+                      tracer=tracer)
+    rng = np.random.default_rng(0)
+    for n in (5, 9, 3):          # the third waits: admitted by a tick
+        eng.submit(list(rng.integers(1, eng.cfg.vocab_size, n)),
+                   max_new_tokens=4)
+
+    def host():
+        snap = eng.metrics_snapshot()
+        return {p: snap[f"host_{p}_s"] for p in TICK_PHASES}
+
+    grew = []
+    while eng._queue or eng._active:
+        tracer.clear()
+        before = host()
+        eng.step()
+        after = host()
+        grew.append({p: after[p] - before[p] for p in TICK_PHASES})
+        (tick,) = tracer.events("serve.tick")
+        assert sum(grew[-1].values()) == pytest.approx(tick["dur_s"],
+                                                       rel=0.05)
+        spans = [r for r in tracer.events() if r["name"] in _PHASE_SPANS]
+        assert sorted(r["name"] for r in spans) == sorted(_PHASE_SPANS)
+        assert sum(r["dur_s"] for r in spans) <= tick["dur_s"]
+        (wait,) = tracer.events("serve.wait")
+        assert wait["dur_s"] > 0
+    assert len(grew) > 2
+    assert all(g["wait"] > 0 for g in grew)
+
+
+def test_request_stamps_are_ordered():
+    tracer = Tracer()
+    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
+                      tracer=tracer)
+    rng = np.random.default_rng(1)
+    for n in (4, 6, 2):
+        eng.submit(list(rng.integers(1, eng.cfg.vocab_size, n)),
+                   max_new_tokens=3)
+    reqs = list(eng._active.values()) + list(eng._queue)
+    eng.run_until_drained()
+    for r in reqs:
+        assert 0 < r.submitted_at <= r.admitted_at <= r.first_token_at \
+            <= r.finished_at
+    recs = {rec["attrs"]["rid"]: rec["attrs"]
+            for rec in tracer.events("serve.request")}
+    assert sorted(recs) == sorted(r.rid for r in reqs)
+    for r in reqs:
+        a = recs[r.rid]
+        assert a["queue_wait_s"] == r.admitted_at - r.submitted_at
+        assert a["ttft_s"] == r.first_token_at - r.submitted_at
+        assert a["e2e_s"] == r.finished_at - r.submitted_at
+        assert (a["prompt_len"], a["output_len"]) == (len(r.prompt), 3)
+
+
+def test_prefill_weight_passes_count_scanned_positions():
+    """A prefill chunk streams the weights once per position of its
+    scan, padding included; the byte counters follow the pass count."""
+    tracer = Tracer()
+    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
+                      pack_weights=True, prefill_chunk=8, tracer=tracer)
+    lens = (5, 20, 1)
+    rng = np.random.default_rng(2)
+    for n in lens:
+        eng.submit(list(rng.integers(1, eng.cfg.vocab_size, n)),
+                   max_new_tokens=2)
+    stats = eng.run_until_drained()
+    chunks = [r["attrs"]["chunk"] for r in tracer.events("serve.prefill")]
+    # every prompt token but the last is prefilled; chunks pad to 2^k
+    assert stats["prefill_tokens"] == sum(n - 1 for n in lens)
+    assert stats["prefill_weight_passes"] == sum(chunks)
+    assert sum(chunks) > stats["prefill_calls"]
+    assert stats["weight_passes"] == (stats["decode_calls"]
+                                      + stats["prefill_weight_passes"])
+    assert check_byte_parity(stats) == []
+
+
+def test_gc_collect_yields_one_span():
+    tracer = Tracer()
+    tracer.watch_gc()
+    tracer.watch_gc()                      # one hook per tracer
+    gc.disable()
+    try:
+        before = (len(tracer.events(GC_SPAN)), tracer.gc_collections,
+                  tracer.gc_pause_ns)
+        gc.collect()
+        recs = tracer.events(GC_SPAN)
+    finally:
+        gc.enable()
+    assert len(recs) == before[0] + 1
+    assert recs[-1]["kind"] == "span"
+    assert recs[-1]["attrs"]["generation"] == 2
+    assert recs[-1]["dur_s"] >= 0
+    assert tracer.gc_collections == before[1] + 1
+    assert tracer.gc_pause_ns > before[2]
 
 
 # -- snapshot purity (satellite: callable mid-run without mutation) -----------
@@ -219,11 +335,17 @@ def test_midrun_snapshot_does_not_perturb_outputs():
 # -- byte accounting (the paper's saving as a live counter) -------------------
 
 def test_byte_parity_fused_vs_analytic_model():
+    k = 2
     eng = SpeculativeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
-                            k=2, pack_weights=True)
+                            k=k, pack_weights=True)
     _, stats = _drain_engine(eng)
     assert check_byte_parity(stats) == []
     assert check_byte_parity(stats, "draft_") == []
+    # a verify streams the target's weights k+1 times; the draft mirrors
+    # every prefill chunk at the same scan width
+    for prefix in ("", "draft_"):
+        assert stats[f"{prefix}weight_passes"] == (
+            stats["spec_ticks"] * (k + 1) + stats["prefill_weight_passes"])
     # and the tolerance is doing work: the counters are real bytes,
     # the model has no lane-tile padding, so they differ but < 1%
     want = stats["weight_passes"] * stats["fused_analytic_bytes_per_pass"]

@@ -13,6 +13,7 @@ The persistent compilation cache is turned off around the module: a
 compile for a described chip cannot be read back without the chip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +125,24 @@ def test_paged_attention_compiles(spec, bits):
              spec((SLOTS, H, HD), jnp.bfloat16), pool, pool,
              spec((SLOTS, SEQ // PAGE), jnp.int32),
              spec((SLOTS,), jnp.int32))
+
+
+def test_kernels_keep_their_names(spec):
+    """The profiler names a kernel's executions by its instruction, which
+    ``pallas_call(name=...)`` fixes: the benchmark's readers find
+    ``packed_matmul.N`` and ``paged_attention.N`` whatever wraps them."""
+    n_pages = SLOTS * SEQ // PAGE
+    pool = spec((n_pages + 1, PAGE, _words(HKV * HD, 16)), jnp.uint32)
+
+    def step(x, w, q, k, v, t, n):
+        return (packed_matmul(x, w, 8, FF, interpret=False),
+                paged_attention(q, k, v, t, n, 16, HKV, interpret=False))
+
+    text = _compile(step, spec((SLOTS, D), jnp.bfloat16),
+                    spec((D, _words(FF, 8)), jnp.uint32),
+                    spec((SLOTS, H, HD), jnp.bfloat16), pool, pool,
+                    spec((SLOTS, SEQ // PAGE), jnp.int32),
+                    spec((SLOTS,), jnp.int32)).as_text()
+    kernels = re.findall(r"%([\w.-]+) = [^\n]*tpu_custom_call", text)
+    assert sorted(re.sub(r"\.\d+$", "", k) for k in kernels) == [
+        "packed_matmul", "paged_attention"]
