@@ -158,7 +158,7 @@ def paged_attention_ref(
     """Fused paged-attention oracle: gather the pages the table names
     into the dense per-sequence view, then run the dense kernels' exact
     math on it. This IS the pre-fused gather-materialize program
-    (``models.lm.gather_kv_pages`` + ``kv_decode_ref`` / the dense
+    (``models.attention.gather_kv_pages`` + ``kv_decode_ref`` / the dense
     softmax), which is what makes fused-vs-gather parity checkable down
     to the bit on the jnp backend. Rows gathered through scrap entries
     sit at positions >= ``kv_len`` where the mask zeroes their softmax

@@ -185,40 +185,117 @@ def paged_decode_attention(
                                 kv_bits or 0, hkv, fallback=fallback)
 
 
+def gather_kv_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """Materialize a per-sequence cache view from the page pool.
+
+    pool: (P, page, W) one layer's physical pages (page 0 = scrap);
+    table: (B, max_pages) int32 physical page ids (0 where unallocated).
+    Returns (B, max_pages*page, W) — logical row ``p`` of sequence
+    ``b`` is pool row (table[b, p // page], p % page). Rows gathered
+    through unallocated (scrap) entries are garbage, but they only ever
+    sit at positions >= the sequence's valid length, where attention
+    masks them — the same dead-row contract the dense cache relies on.
+
+    In decode this is the *demoted* paged path: the fused kernel
+    (``kernels.paged_attention``) attends through the table without ever
+    building this view, so the dispatch record here lets the static
+    linter prove a fused-configured decode trace never materialized the
+    gather. The one-pass prefill reads this view in both paged modes
+    (its C queries have no fused kernel), which keeps paged prefill
+    bitwise equal to dense prefill.
+    """
+    kops.record_dispatch("gather_kv_pages", "materialized",
+                         pool.size * pool.dtype.itemsize,
+                         shape=pool.shape)
+    g = jnp.take(pool, table, axis=0)          # (B, mp, page, W)
+    b, mp, pg = g.shape[0], g.shape[1], g.shape[2]
+    return g.reshape((b, mp * pg) + g.shape[3:])
+
+
 def _kv_rows(x_new, kv_bits):
-    """One token's (B, Hkv, D) K or V -> its (B, kv_row_width) cache row
+    """K or V of shape (..., Hkv, D) -> cache rows (..., kv_row_width)
     (``kops.pack`` over the flattened heads when the cache packs)."""
-    row = x_new.reshape(x_new.shape[0], -1)
+    row = x_new.reshape(x_new.shape[:-2] + (-1,))
     return kops.pack(row.astype(jnp.float32), kv_bits) if kv_bits else row
 
 
-def append_kv_pool_row(k_pool, v_pool, k_new, v_new, table, kv_len,
-                       kv_bits: Optional[int] = None):
-    """Persist one token's K/V row straight to its physical page — the
-    fused paged path's append. The row packs exactly as
-    ``update_kv_cache`` packs it (same ``_kv_rows``), so the pool holds
-    bit-identical words whether the row arrived here or through the
-    gather-view + ``scatter_kv_row`` round-trip. Out-of-range lengths
-    (dead slots) clamp onto the scrap page, mirroring
-    ``scatter_kv_row``."""
-    return (_scatter_pool_row(k_pool, _kv_rows(k_new, kv_bits), table,
-                              kv_len),
-            _scatter_pool_row(v_pool, _kv_rows(v_new, kv_bits), table,
-                              kv_len))
+def append_kv_pool_rows(k_pool, v_pool, k_new, v_new, table, kv_len,
+                        kv_bits: Optional[int] = None):
+    """Persist C tokens' K/V rows, (B, C, Hkv, D), straight to their
+    physical pages at positions ``kv_len[b] + i`` — the paged append of
+    decode (C = 1) and of the one-pass prefill. Rows pack exactly as
+    ``update_kv_cache`` packs them (same ``_kv_rows``), so the pool holds
+    bit-identical words whether a row arrived here or through the
+    gather-view + ``scatter_kv_row`` round-trip. Out-of-range positions
+    (dead slots, padding past the cache) clamp onto the last table entry
+    (the scrap page where unallocated), mirroring ``scatter_kv_row``."""
+    return (_scatter_pool_rows(k_pool, _kv_rows(k_new, kv_bits), table,
+                               kv_len),
+            _scatter_pool_rows(v_pool, _kv_rows(v_new, kv_bits), table,
+                               kv_len))
 
 
-def _scatter_pool_row(pool, row, table, kv_len):
-    """Write each sequence's row at pool position
-    (table[b, len // page], len % page)."""
+def _scatter_pool_rows(pool, rows, table, kv_len):
+    """Write row i of sequence b, ``rows[b, i]``, at pool position
+    (table[b, p // page], p % page) with p = kv_len[b] + i."""
     page = pool.shape[1]
     mp = table.shape[1]
-    pos = jnp.minimum(kv_len, mp * page - 1)
-    pidx = jnp.minimum(pos // page, mp - 1)
-    ids = jnp.take_along_axis(table, pidx[:, None], axis=1)[:, 0]
-    phys = ids * page + pos % page
+    pos = jnp.minimum(kv_len[:, None] + jnp.arange(rows.shape[1]),
+                      mp * page - 1)                         # (B, C)
+    ids = jnp.take_along_axis(table, pos // page, axis=1)
+    phys = (ids * page + pos % page).reshape(-1)
     flat = pool.reshape((-1,) + pool.shape[2:])
-    flat = flat.at[phys].set(row.astype(flat.dtype))
+    flat = flat.at[phys].set(
+        rows.reshape((-1,) + rows.shape[2:]).astype(flat.dtype))
     return flat.reshape(pool.shape)
+
+
+def append_kv_cache_rows(k_cache, v_cache, k_new, v_new, kv_len,
+                         kv_bits: Optional[int] = None):
+    """Dense-cache twin of :func:`append_kv_pool_rows`: C tokens' K/V,
+    (B, C, Hkv, D), packed as decode packs them, written at cache rows
+    ``kv_len[b] + i``. Rows past the end of the cache are dropped, so a
+    chunk's padding never clamps back onto a valid row."""
+    return (_set_cache_rows(k_cache, _kv_rows(k_new, kv_bits), kv_len),
+            _set_cache_rows(v_cache, _kv_rows(v_new, kv_bits), kv_len))
+
+
+def _set_cache_rows(cache, rows, kv_len):
+    pos = kv_len[:, None] + jnp.arange(rows.shape[1])      # (B, C)
+    b = jnp.arange(cache.shape[0])[:, None]
+    return cache.at[b, pos].set(rows.astype(cache.dtype), mode="drop")
+
+
+def prefill_attention(
+    q: jnp.ndarray,                        # (B, C, H, D) new tokens
+    k_cache, v_cache,                      # (B, S, kv_row_width) rows
+    kv_len: jnp.ndarray,                   # (B,) length before the chunk
+    kv_bits: Optional[int],
+    hkv: int,
+) -> jnp.ndarray:
+    """Score C new tokens per sequence against a cache view that already
+    holds their own rows: query i sits at position ``kv_len + i`` and
+    sees keys at positions up to and including its own. Packed rows are
+    unpacked in XLA (``kops.unpack``, the oracles' decode) and scored in
+    float32 like the jnp path of :func:`decode_attention`, so every key a
+    query sees went through the cache's format, as in decode."""
+    b, c, h, d = q.shape
+    s = k_cache.shape[1]
+    g = h // hkv
+
+    def rows(cache):
+        x = (kops.unpack(cache, kv_bits, hkv * d) if kv_bits
+             else cache.astype(jnp.float32))
+        return x.reshape(b, s, hkv, d)
+
+    qg = q.reshape(b, c, hkv, g, d).astype(jnp.float32)
+    logits = jnp.einsum("bckgd,bskd->bkgcs", qg, rows(k_cache)) / np.sqrt(d)
+    last = kv_len[:, None] + jnp.arange(c)                   # (B, C)
+    mask = jnp.arange(s)[None, None, :] <= last[:, :, None]  # (B, C, S)
+    logits = jnp.where(mask[:, None, None], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgcs,bskd->bckgd", p, rows(v_cache))
+    return out.reshape(b, c, h, d).astype(q.dtype)
 
 
 def update_kv_cache(k_cache, v_cache, k_new, v_new, kv_len,

@@ -19,10 +19,13 @@ import numpy as np
 from repro.distributed.sharding import constrain
 from repro.models import layers as L
 from repro.models.attention import (
-    append_kv_pool_row,
+    append_kv_cache_rows,
+    append_kv_pool_rows,
     decode_attention,
     flash_attention,
+    gather_kv_pages,
     paged_decode_attention,
+    prefill_attention,
     update_kv_cache,
 )
 from repro.models.config import ModelConfig
@@ -145,7 +148,7 @@ def attention_decode_paged(
 ) -> Tuple[jnp.ndarray, Dict]:
     """Fused-paged twin of :func:`attention_decode` (self-attention
     only): the same q/k/v/rope program, but the new row persists straight
-    to its physical page (``append_kv_pool_row``) and attention walks the
+    to its physical page (``append_kv_pool_rows``) and attention walks the
     pool through the table (``kernels.paged_attention``) — the dense
     gathered view never materializes. ``kv`` is one layer's pool slice
     ``{"k", "v"}`` of shape (P+1, page, kv_row_width). ``oracle=True`` routes
@@ -165,11 +168,51 @@ def attention_decode_paged(
         k = L.rms_norm(k, p["k_norm"])
     q = L.rope(q, positions, cfg.rope_theta)
     k = L.rope(k, positions, cfg.rope_theta)
-    kc, vc = append_kv_pool_row(kv["k"], kv["v"], k[:, 0], v[:, 0],
-                                table, kv_len, kv_bits)
+    kc, vc = append_kv_pool_rows(kv["k"], kv["v"], k, v,
+                                 table, kv_len, kv_bits)
     o = paged_decode_attention(q[:, 0], kc, vc, table, kv_len + 1,
                                kv_bits, hkv, fallback=oracle)
     return x + L.linear(o.reshape(b, 1, h * hd), p["wo"],
+                        "...f,fd->...d"), {"k": kc, "v": vc}
+
+
+def attention_prefill(
+    p: Dict, x: jnp.ndarray, cfg: ModelConfig,
+    kv: Dict, table: Optional[jnp.ndarray], kv_len: jnp.ndarray,
+    positions: jnp.ndarray,
+    kv_bits_override: Optional[int] = None,
+) -> Tuple[jnp.ndarray, Dict]:
+    """Multi-token twin of :func:`attention_decode` for a prompt chunk:
+    x (B, C, d) at ``positions`` = ``kv_len[:, None] + arange(C)``. The
+    q/k/v projections see all B·C rows at once; the C new K/V rows are
+    written into ``kv`` — one layer's dense (B, S, row) cache when
+    ``table`` is None, else its page pool through the table — packed as
+    the decode append packs them; then the C queries attend to the cache
+    view after the write, dense or gathered (``gather_kv_pages``)."""
+    b, c, _ = x.shape
+    hd, h, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
+    xn = L.rms_norm(x, p["ln"])
+    q = L.linear(xn, p["wq"]).reshape(b, c, h, hd)
+    k = L.linear(xn, p["wk"]).reshape(b, c, hkv, hd)
+    v = L.linear(xn, p["wv"]).reshape(b, c, hkv, hd)
+    if cfg.qk_norm:
+        q = L.rms_norm(q, p["q_norm"])
+        k = L.rms_norm(k, p["k_norm"])
+    q = L.rope(q, positions, cfg.rope_theta)
+    k = L.rope(k, positions, cfg.rope_theta)
+    kv_bits = (kv_bits_override if kv_bits_override is not None
+               else cfg.compression.kv_bits)
+    if table is None:
+        kc, vc = append_kv_cache_rows(kv["k"], kv["v"], k, v, kv_len,
+                                      kv_bits)
+        k_view, v_view = kc, vc
+    else:
+        kc, vc = append_kv_pool_rows(kv["k"], kv["v"], k, v, table,
+                                     kv_len, kv_bits)
+        k_view, v_view = gather_kv_pages(kc, table), gather_kv_pages(
+            vc, table)
+    o = prefill_attention(q, k_view, v_view, kv_len, kv_bits, hkv)
+    return x + L.linear(o.reshape(b, c, h * hd), p["wo"],
                         "...f,fd->...d"), {"k": kc, "v": vc}
 
 

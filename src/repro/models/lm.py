@@ -13,9 +13,10 @@ Public surface (used by train/serve/dryrun):
   * ``decode_step(params, state, tokens)`` -> (logits, state)
   * ``verify_step(params, state, tokens)`` -> (all-position logits, state)
     — multi-token decode (KV-append per position, one call): the
-    speculative-verify / chunked-prefill path
+    speculative-verify path, and prefill of the scanned families
   * ``prefill_step(params, state, tokens, n_valid)`` -> state — chunked
-    prompt ingestion through the decode KV-append path
+    prompt ingestion: one multi-token forward per chunk for the dense and
+    vlm families, a scan of decode steps for the others
   * ``rollback_decode_state(state, lengths)`` -> state — roll the KV back
     to per-sequence lengths (speculation rejects; in paged mode the
     serving layer then frees the pages past the committed length)
@@ -38,10 +39,9 @@ import numpy as np
 from repro import compat
 
 from repro.distributed.sharding import constrain
-from repro.kernels import ops as kops
 from repro.models import blocks as B
 from repro.models import layers as L
-from repro.models.attention import kv_row_width
+from repro.models.attention import gather_kv_pages, kv_row_width
 from repro.models.config import ModelConfig, ShapeConfig
 
 
@@ -77,30 +77,6 @@ def _is_part(x) -> bool:
 # ---------------------------------------------------------------------------
 # Paged KV: page-table indirection over a pooled physical cache
 # ---------------------------------------------------------------------------
-
-def gather_kv_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    """Materialize a per-sequence cache view from the page pool.
-
-    pool: (P, page, W) one layer's physical pages (page 0 = scrap);
-    table: (B, max_pages) int32 physical page ids (0 where unallocated).
-    Returns (B, max_pages*page, W) — logical row ``p`` of sequence
-    ``b`` is pool row (table[b, p // page], p % page). Rows gathered
-    through unallocated (scrap) entries are garbage, but they only ever
-    sit at positions >= the sequence's valid length, where attention
-    masks them — the same dead-row contract the dense cache relies on.
-
-    This is the *demoted* paged path: the fused kernel
-    (``kernels.paged_attention``) attends through the table without ever
-    building this view, so the dispatch record here lets the static
-    linter prove a fused-configured trace never materialized the gather.
-    """
-    kops.record_dispatch("gather_kv_pages", "materialized",
-                         pool.size * pool.dtype.itemsize,
-                         shape=pool.shape)
-    g = jnp.take(pool, table, axis=0)          # (B, mp, page, W)
-    b, mp, pg = g.shape[0], g.shape[1], g.shape[2]
-    return g.reshape((b, mp * pg) + g.shape[3:])
-
 
 def scatter_kv_row(pool: jnp.ndarray, view: jnp.ndarray,
                    table: jnp.ndarray, lens: jnp.ndarray) -> jnp.ndarray:
@@ -750,13 +726,25 @@ class LM:
         un-folded, so they cannot serve as speculation targets."""
         return self.cfg.family in ("dense", "moe", "vlm", "encdec")
 
-    def weight_passes(self, positions: int) -> int:
-        """Passes over the weights that one ``verify_step`` or
-        ``prefill_step`` call over ``positions`` token positions streams:
-        the call scans the single-token decode step once per position, so
-        every position reads every weight. The engines' byte counters
-        multiply their per-pass bytes by this."""
-        return positions
+    @property
+    def one_pass_prefill(self) -> bool:
+        """True when :meth:`prefill_step` runs a chunk as one multi-token
+        forward (attention + dense MLP: the dense and vlm families). The
+        others scan the decode step: ``moe_ffn`` sizes expert capacity
+        from the token count, so an idle slot's padding rows would
+        compete with real tokens for it; encdec's cross attention has no
+        multi-query form; ssm and hybrid never prefill in chunks."""
+        return self.cfg.family in ("dense", "vlm")
+
+    def weight_passes(self, positions: int, prefill: bool = False) -> int:
+        """Passes over the weights that one ``verify_step`` call over
+        ``positions`` token positions streams — it scans the single-token
+        decode step once per position, so every position reads every
+        weight — or, with ``prefill``, one ``prefill_step`` chunk that
+        wide: one pass where :attr:`one_pass_prefill`, else the scan's
+        one per position. The engines' byte counters multiply their
+        per-pass bytes by this."""
+        return 1 if prefill and self.one_pass_prefill else positions
 
     def verify_step(self, params, state: Dict,
                     tokens: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
@@ -780,14 +768,59 @@ class LM:
     def prefill_step(self, params, state: Dict, tokens: jnp.ndarray,
                      n_valid: jnp.ndarray) -> Dict:
         """Ingest a prompt chunk: tokens (B, C), n_valid (B,) of them real
-        per sequence. Appends through the same KV path as decode and then
-        sets ``len = len_before + n_valid`` — the padding rows land past
-        the valid length, where they are masked out and later overwritten,
-        so sequences with shorter chunks (or none: n_valid = 0) stay
-        byte-exact with never having stepped at all."""
+        per sequence; sets ``len = len_before + n_valid``. The padding
+        rows land past the valid length, where they are masked out and
+        later overwritten, so sequences with shorter chunks (or none:
+        n_valid = 0) keep their valid rows byte for byte.
+
+        Where :attr:`one_pass_prefill`, the chunk is one forward over all
+        B·C rows (:meth:`_prefill_forward`): each weight is read once per
+        chunk, and the K/V rows match the decode path's within float
+        rounding, not bitwise. Other families scan :meth:`verify_step`,
+        bitwise equal to C decode steps."""
         len0 = state["len"]
-        _, state = self.verify_step(params, state, tokens)
+        if self.one_pass_prefill:
+            state = self._prefill_forward(params, state, tokens)
+        else:
+            _, state = self.verify_step(params, state, tokens)
         return dict(state, len=len0 + jnp.asarray(n_valid, jnp.int32))
+
+    def _prefill_forward(self, params, state: Dict,
+                         tokens: jnp.ndarray) -> Dict:
+        """One forward of a (B, C) chunk through the dense/vlm stack:
+        write each layer's C K/V rows at ``len .. len + C - 1`` and attend
+        them causally against the cache (``blocks.attention_prefill``).
+        No head: only the cache is kept. ``len`` is left as it was."""
+        cfg = self.cfg
+        table = state.get("table")
+        len0 = state["len"]
+        x = L.embed(tokens, params["embed"]).astype(cfg.dtype)
+        x = constrain(x, ("data", None, None))
+        positions = len0[:, None] + jnp.arange(tokens.shape[1],
+                                               dtype=jnp.int32)
+
+        def body_at(bits):
+            def body(h, xs):
+                lp, kv = xs
+                h, new_kv = B.attention_prefill(
+                    lp["attn"], h, cfg, kv, table, len0, positions,
+                    kv_bits_override=bits)
+                return B.mlp_apply(lp["mlp"], h, cfg), new_kv
+            return body
+
+        if not isinstance(state["kv"], tuple):
+            _, new_kv = jax.lax.scan(body_at(None), x,
+                                     (params["blocks"], state["kv"]))
+            return dict(state, kv=new_kv)
+        # width-segmented cache: one scan per equal-width layer run, as
+        # in decode_step
+        new_segs = []
+        for (lo, hi, bits), kv_seg in zip(cfg.kv_segments(), state["kv"]):
+            blocks = compat.tree_map(lambda a, lo=lo, hi=hi: a[lo:hi],
+                                     params["blocks"])
+            x, new_kv = jax.lax.scan(body_at(bits), x, (blocks, kv_seg))
+            new_segs.append(new_kv)
+        return dict(state, kv=tuple(new_segs))
 
     def rollback_decode_state(self, state: Dict,
                               lengths: jnp.ndarray) -> Dict:

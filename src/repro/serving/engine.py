@@ -16,7 +16,10 @@ batch -> each weight read amortized over more tokens -> tokens/s up.
   * **chunked prefill**: admitted prompts stream through
     ``lm.prefill_step`` ``prefill_chunk`` tokens at a time (one jitted
     multi-token KV-append per chunk), so a long prompt costs
-    ceil(len/chunk) calls instead of one decode tick per prompt token;
+    ceil(len/chunk) calls instead of one decode tick per prompt token.
+    On the dense and vlm families a chunk is one forward over all
+    ``n_slots x chunk`` rows, one pass over the weights; the others scan
+    the decode step, one pass per position (``LM.weight_passes``);
   * decode runs one jitted ``decode_step`` over the whole slot array per
     tick. The per-tick token generation lives in ``_generate`` — a
     pluggable stepper: ``serving.speculative.SpeculativeEngine`` overrides
@@ -739,7 +742,7 @@ class ServeEngine:
                       n_valid: jnp.ndarray) -> None:
         """One chunked KV-append over the slot array. Overridable — the
         speculative engine mirrors every chunk into its draft cache."""
-        passes = self.lm.weight_passes(tokens.shape[1])
+        passes = self.lm.weight_passes(tokens.shape[1], prefill=True)
         self._prefill_calls += 1
         self._prefill_weight_passes += passes
         self._weight_passes += passes

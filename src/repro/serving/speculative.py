@@ -553,7 +553,7 @@ class SpeculativeEngine(ServeEngine):
                       n_valid: jnp.ndarray) -> None:
         super()._prefill_call(tokens, n_valid)
         self._count_draft_passes(
-            self.draft_lm.weight_passes(tokens.shape[1]))
+            self.draft_lm.weight_passes(tokens.shape[1], prefill=True))
         self._draft_kv_rows_appended += int(np.asarray(n_valid).sum())
         self.draft_state = self._draft_prefill(
             self.draft_params, self.draft_state, tokens, n_valid)

@@ -230,6 +230,36 @@ def test_dispatch_lint_clean(dense_cfg, dense_params):
     assert not [f for f in findings if f.severity == "error"]
 
 
+def test_dispatch_lint_one_pass_prefill_paged(dense_cfg, monkeypatch):
+    """The one-pass prefill_step over a paged state, traced with a packed
+    plan in place of the linter's own entry points: no fallback, no
+    planned leaf unpacked wholesale, and a fused proof for every planned
+    leaf. Prefill has no head, so the config ties its embeddings: every
+    planned leaf is then one the prefill reads."""
+    import repro.analysis.dispatch as dispatch
+    from repro.models.lm import LM
+
+    cfg = dataclasses.replace(dense_cfg, tie_embeddings=True)
+    lm = LM(cfg)
+    assert lm.one_pass_prefill
+
+    def trace_paged_prefill(cfg_, packed, masters, batch_size=1,
+                            seq_len=32):
+        state = lm.init_paged_decode_state(batch_size, seq_len, 8,
+                                           batch_size * 4, abstract=True)
+        jax.make_jaxpr(lm.prefill_step)(
+            packed, state, jnp.zeros((batch_size, 4), jnp.int32),
+            jnp.full((batch_size,), 4, jnp.int32))
+        return ["paged_prefill_step"], []
+
+    monkeypatch.setattr(dispatch, "trace_entry_points", trace_paged_prefill)
+    findings, traced = lint_dispatch(cfg,
+                                     params=lm.init(compat.prng_key(0)))
+    assert traced == ["paged_prefill_step"]
+    assert not [f for f in findings if f.severity != "info"], findings
+    assert any("proven fused" in f.message for f in findings)
+
+
 def test_dispatch_lint_catches_seeded_fallback(dense_cfg, dense_params):
     from repro.analysis.lint import _inject_fallback
     findings, _ = lint_dispatch(

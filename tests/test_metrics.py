@@ -197,11 +197,10 @@ def test_drain_reuses_snapshot_counters():
             continue      # process-wide: a collection may fall between
         assert stats[key] == val, key
     assert stats["wall_s"] > 0
-    # a prefill chunk's scan streams the weights once per position
-    positions = sum(r["attrs"]["chunk"]
-                    for r in tracer.events("serve.prefill"))
+    # a dense prefill chunk is one forward: one pass over the weights
     assert stats["prefill_calls"] > 0
-    assert stats["weight_passes"] == stats["decode_calls"] + positions
+    assert stats["weight_passes"] == (stats["decode_calls"]
+                                      + stats["prefill_calls"])
 
 
 # -- the serving tick: host phases, request stamps, true weight passes -------
@@ -269,12 +268,19 @@ def test_request_stamps_are_ordered():
         assert (a["prompt_len"], a["output_len"]) == (len(r.prompt), 3)
 
 
-def test_prefill_weight_passes_count_scanned_positions():
-    """A prefill chunk streams the weights once per position of its
-    scan, padding included; the byte counters follow the pass count."""
+@pytest.mark.parametrize("arch,speculative", [("qwen3_8b", False),
+                                               ("qwen3_8b", True),
+                                               ("deepseek_moe_16b", False)])
+def test_prefill_weight_passes_count_scanned_positions(arch, speculative):
+    """A dense prefill chunk is one forward, one pass over the weights
+    (the speculative draft mirrors it); a moe chunk scans the decode
+    step, one pass per position, padding included. The byte counters
+    follow the pass count."""
     tracer = Tracer()
-    eng = ServeEngine(_tiny_cfg(), max_seq_len=64, max_slots=2,
-                      pack_weights=True, prefill_chunk=8, tracer=tracer)
+    kw = dict(max_seq_len=64, max_slots=2, pack_weights=True,
+              prefill_chunk=8, tracer=tracer)
+    eng = (SpeculativeEngine(_tiny_cfg(arch), k=2, **kw) if speculative
+           else ServeEngine(_tiny_cfg(arch), **kw))
     lens = (5, 20, 1)
     rng = np.random.default_rng(2)
     for n in lens:
@@ -284,10 +290,22 @@ def test_prefill_weight_passes_count_scanned_positions():
     chunks = [r["attrs"]["chunk"] for r in tracer.events("serve.prefill")]
     # every prompt token but the last is prefilled; chunks pad to 2^k
     assert stats["prefill_tokens"] == sum(n - 1 for n in lens)
-    assert stats["prefill_weight_passes"] == sum(chunks)
-    assert sum(chunks) > stats["prefill_calls"]
-    assert stats["weight_passes"] == (stats["decode_calls"]
-                                      + stats["prefill_weight_passes"])
+    assert stats["prefill_calls"] == len(chunks)
+    assert sum(chunks) > len(chunks)
+    one_pass = eng.lm.one_pass_prefill
+    assert one_pass == (arch == "qwen3_8b")
+    assert stats["prefill_weight_passes"] == (len(chunks) if one_pass
+                                              else sum(chunks))
+    if speculative:
+        assert stats["draft_weight_passes"] == (
+            stats["spec_ticks"] * (eng.k + 1) + len(chunks))
+        assert stats["weight_passes"] == (
+            stats["spec_ticks"] * (eng.k + 1)
+            + stats["prefill_weight_passes"])
+        assert check_byte_parity(stats, "draft_") == []
+    else:
+        assert stats["weight_passes"] == (stats["decode_calls"]
+                                          + stats["prefill_weight_passes"])
     assert check_byte_parity(stats) == []
 
 

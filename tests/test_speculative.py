@@ -300,9 +300,42 @@ def test_verify_step_matches_sequential_decode_bitwise():
     assert jnp.array_equal(st_seq["kv"]["k"], st_v["kv"]["k"])
 
 
-def test_prefill_step_chunked_matches_sequential():
-    cfg = _tiny_cfg()
+# One-pass prefill (dense, vlm) and the scanned decode compute the same
+# K/V with different summation orders: B·C-row matmuls and a C-query
+# softmax against B-row matmuls and one-query softmaxes. The tiny
+# configs run float32 activations, so that moves a value by about 1e-7
+# of itself, and a stored AF16 (e5m10) cache word can only cross one
+# rounding boundary: one ulp, 2^-10 of its binade. Two ulps leave room
+# for a flipped word feeding the next layer; 2^-24 is AF16's least
+# subnormal step.
+KV_RTOL = 2.0 ** -9
+KV_ATOL = 2.0 ** -24
+
+
+def _kv_floats(cfg, words):
+    """Cache rows (..., kv_row_width) -> their float values."""
+    from repro.kernels.ref import unpack_ref
+    bits = cfg.compression.kv_bits
+    n = cfg.n_kv_heads * cfg.resolved_head_dim
+    return np.asarray(unpack_ref(jnp.asarray(words), bits, n)
+                      if bits else words, np.float32)
+
+
+def _assert_kv_close(cfg, got, want):
+    np.testing.assert_allclose(_kv_floats(cfg, got), _kv_floats(cfg, want),
+                               rtol=KV_RTOL, atol=KV_ATOL)
+
+
+@pytest.mark.parametrize("arch,one_pass", [("qwen3_8b", True),
+                                           ("deepseek_moe_16b", False),
+                                           ("whisper_small", False)])
+def test_prefill_step_chunked_matches_sequential(arch, one_pass):
+    """Valid rows of a prefilled slot against the same tokens fed one
+    decode step at a time: bitwise where prefill scans the decode step
+    (moe, encdec), within KV_RTOL where it is one forward."""
+    cfg = _tiny_cfg(arch)
     lm = LM(cfg)
+    assert lm.one_pass_prefill is one_pass
     params = lm.init(prng_key(0))
     B, S, C = 2, 32, 6
     rng = np.random.default_rng(5)
@@ -319,9 +352,134 @@ def test_prefill_step_chunked_matches_sequential():
         _, st_ref = lm.decode_step(params, st_ref,
                                    jnp.asarray(toks[:, i:i + 1]))
     # valid rows of the prefilled slot match the sequential feed
-    k_chunk = np.asarray(st["kv"]["k"])[:, 0, :4]
-    k_ref = np.asarray(st_ref["kv"]["k"])[:, 0, :4]
-    assert np.array_equal(k_chunk, k_ref)
+    for key in ("k", "v"):
+        got = np.asarray(st["kv"][key])[:, 0, :4]
+        want = np.asarray(st_ref["kv"][key])[:, 0, :4]
+        if one_pass:
+            _assert_kv_close(cfg, got, want)
+        else:
+            assert np.array_equal(got, want), key
+
+
+def _paged_state(lm, B, S, page, seed=0):
+    """A paged state whose table maps each slot onto shuffled pages, so
+    the indirection is not the identity."""
+    mp = S // page
+    st = lm.init_paged_decode_state(B, S, page, B * mp)
+    ids = np.random.default_rng(seed).permutation(B * mp) + 1
+    return dict(st, table=jnp.asarray(ids.reshape(B, mp), jnp.int32))
+
+
+def _view_rows(state, key, slot, n):
+    """Rows 0..n-1 of ``slot`` in layer order, dense or paged."""
+    kv = np.asarray(state["kv"][key])
+    if "table" not in state:
+        return kv[:, slot, :n]
+    tab = np.asarray(state["table"])[slot]
+    rows = kv[:, tab].reshape(kv.shape[0], -1, kv.shape[-1])
+    return rows[:, :n]
+
+
+def _prefill_chunks(lm, params, state, prompts, chunk):
+    """Prefill every prompt in ``chunk``-wide calls, as the engine does."""
+    step = jax.jit(lm.prefill_step)
+    B = len(prompts)
+    done = [0] * B
+    while any(d < len(p) for d, p in zip(done, prompts)):
+        toks = np.zeros((B, chunk), np.int32)
+        n_valid = np.zeros((B,), np.int32)
+        for b, p in enumerate(prompts):
+            take = min(chunk, len(p) - done[b])
+            toks[b, :take] = p[done[b]:done[b] + take]
+            n_valid[b] = take
+            done[b] += take
+        state = step(params, state, jnp.asarray(toks), jnp.asarray(n_valid))
+    return state
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_one_pass_prefill_matches_scanned_decode(packed):
+    """Multi-chunk prompts of unequal lengths through the one-pass
+    prefill, dense and paged: K/V within KV_RTOL of the scanned decode
+    over the same tokens, and the paged pool bitwise equal to the dense
+    cache (both attend to the same view)."""
+    cfg = _tiny_cfg()
+    lm = LM(cfg)
+    params = lm.init(prng_key(1))
+    if packed:
+        params = repack(params, uniform_plan(params, 8))
+    B, S, page = 3, 32, 8
+    rng = np.random.default_rng(6)
+    prompts = [list(rng.integers(1, cfg.vocab_size, n)) for n in (21, 9, 0)]
+    dense = _prefill_chunks(lm, params, lm.init_decode_state(B, S),
+                            prompts, 8)
+    paged = _prefill_chunks(lm, params, _paged_state(lm, B, S, page),
+                            prompts, 8)
+    T = max(len(p) for p in prompts)
+    full = np.zeros((B, T), np.int32)
+    for b, p in enumerate(prompts):
+        full[b, :len(p)] = p
+    _, ref = jax.jit(lm.verify_step)(params, lm.init_decode_state(B, S),
+                                     jnp.asarray(full))
+    for st in (dense, paged):
+        assert np.asarray(st["len"]).tolist() == [21, 9, 0]
+    for key in ("k", "v"):
+        for b, p in enumerate(prompts):
+            got = _view_rows(dense, key, b, len(p))
+            assert np.array_equal(_view_rows(paged, key, b, len(p)), got)
+            _assert_kv_close(cfg, got, _view_rows(ref, key, b, len(p)))
+
+
+def test_prefill_chunk_width_keeps_greedy_tokens():
+    """A prompt prefilled in chunks of 4 against chunks of 16: K/V within
+    KV_RTOL, and the engine's greedy tokens the same."""
+    cfg = _tiny_cfg()
+    lm = LM(cfg)
+    params = lm.init(prng_key(2))
+    rng = np.random.default_rng(7)
+    prompts = [list(rng.integers(1, cfg.vocab_size, n)) for n in (16, 11)]
+    narrow = _prefill_chunks(lm, params, lm.init_decode_state(2, 32),
+                             prompts, 4)
+    wide = _prefill_chunks(lm, params, lm.init_decode_state(2, 32),
+                           prompts, 16)
+    for key in ("k", "v"):
+        for b, p in enumerate(prompts):
+            _assert_kv_close(cfg, _view_rows(narrow, key, b, len(p)),
+                             _view_rows(wide, key, b, len(p)))
+
+    mix = [list(rng.integers(1, cfg.vocab_size, n))
+           for n in (0, 3, 15, 16, 17, 40)]
+    outs = []
+    for chunk in (4, 16):
+        eng = ServeEngine(cfg, max_seq_len=64, max_slots=3,
+                          prefill_chunk=chunk)
+        rids = [eng.submit(p, max_new_tokens=6) for p in mix]
+        eng.run_until_drained()
+        outs.append([eng.result(r) for r in rids])
+    assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_prefill_idle_slots_keep_len_and_rows(layout):
+    """A slot that rides along with n_valid = 0 keeps its length and its
+    valid rows byte for byte, also where the chunk's padding would run
+    past the end of its cache."""
+    cfg = _tiny_cfg()
+    lm = LM(cfg)
+    params = lm.init(prng_key(3))
+    B, S, page = 2, 16, 8
+    st = (lm.init_decode_state(B, S) if layout == "dense"
+          else _paged_state(lm, B, S, page))
+    rng = np.random.default_rng(8)
+    step = jax.jit(lm.prefill_step)
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 16)), jnp.int32)
+    st = step(params, st, toks, jnp.asarray([3, 14], jnp.int32))
+    before = {key: _view_rows(st, key, 1, 14) for key in ("k", "v")}
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 8)), jnp.int32)
+    st = step(params, st, toks, jnp.asarray([8, 0], jnp.int32))
+    assert np.asarray(st["len"]).tolist() == [11, 14]
+    for key in ("k", "v"):
+        assert np.array_equal(_view_rows(st, key, 1, 14), before[key])
 
 
 def test_rollback_is_length_reset_and_gated_by_family():

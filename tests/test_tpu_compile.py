@@ -74,6 +74,15 @@ def test_packed_matmul_compiles(spec, bits, k, n):
              spec((k, _words(n, bits)), jnp.uint32))
 
 
+@pytest.mark.parametrize("k,n", [(D, FF), (FF, D), (D, HKV * HD)])
+def test_packed_matmul_prefill_rows_compiles(spec, k, n):
+    """A one-pass prefill chunk: 16 slots x 16 tokens reach the kernel
+    as one (256, k) operand, two of its 128-row M blocks."""
+    _compile(lambda x, w: packed_matmul(x, w, 8, n, interpret=False),
+             spec((16, 16, k), jnp.bfloat16),
+             spec((k, _words(n, 8)), jnp.uint32))
+
+
 @pytest.mark.parametrize("bits", [8, 12, 16])
 def test_packed_matmul_transpose_compiles(spec, bits):
     """The tied-unembed orientation: contraction over the packed axis of
